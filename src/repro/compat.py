@@ -1,31 +1,9 @@
-"""Version compatibility shims for the installed JAX.
+"""The sharding entry points every module uses, spelled in one place.
 
-The codebase targets the current `jax.shard_map` API (with ``check_vma``);
-older releases only ship `jax.experimental.shard_map.shard_map` (with the
-equivalent flag spelled ``check_rep``). Everything that shards goes through
-this one wrapper so the rest of the tree can use the modern spelling.
+The tree targets the installed JAX, whose `jax.shard_map` (with
+``check_vma``) and `jax.make_mesh` are used as they are.
 """
 
-from __future__ import annotations
+from jax import make_mesh, shard_map
 
-import jax
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """`jax.shard_map` with fallback to the experimental module."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
-
-
-def make_mesh(axis_shapes, axis_names):
-    """`jax.make_mesh` with fallback to a manual device reshape."""
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(axis_shapes, axis_names)
-    import numpy as np
-    from jax.sharding import Mesh
-    devs = np.asarray(jax.devices()).reshape(axis_shapes)
-    return Mesh(devs, axis_names)
+__all__ = ["make_mesh", "shard_map"]

@@ -45,8 +45,8 @@ overlap additionally needs chunks | N1/D and chunks | N2/D.
 
 Twiddle note: W_N^{i2*o1} exponents reach N1*N2 ~ 2^40+, far beyond f32
 integer precision. Since N is a power of two, `(i2 * o1) mod N` is computed
-exactly in uint32 wrap-around arithmetic (mod 2^32 then mask), keeping the
-twiddle angles exact for any N <= 2^32.
+exactly in int32 wrap-around arithmetic (mod 2^32 then mask), keeping the
+twiddle angles exact for any N <= 2^32 (`kernels.fft.matfft.twiddle`).
 
 `build_distributed` is the strategy builder the `repro.fft` planner
 consumes (the planner owns the single jit); `distributed_fft` remains as
@@ -65,6 +65,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro import compat
 from repro.fft import executors as fft_ex
 from repro.kernels.fft import plan as fft_plan
+from repro.kernels.fft.matfft import twiddle
 
 # overlap="auto" heuristic bounds (DESIGN.md §8): below AUTO_MIN_N the
 # per-round ppermute latency exceeds the compute the pipeline could hide
@@ -358,10 +359,8 @@ def _ring(d: int, ax, didx, take, place, bufs):
 
 def _twiddle(i2g: jnp.ndarray, o1: jnp.ndarray, n: int):
     """Planar W_n^{i2g*o1} with exact pow2 modular exponent (see header)."""
-    m = (i2g.astype(jnp.uint32)[:, None] * o1.astype(jnp.uint32)[None, :])
-    m = m & jnp.uint32(n - 1)
-    ang = (-2.0 * math.pi / n) * m.astype(jnp.float32)
-    return jnp.cos(ang), jnp.sin(ang)
+    return twiddle(i2g.astype(jnp.int32)[:, None],
+                   o1.astype(jnp.int32)[None, :], n)
 
 
 def build_distributed(n: int, mesh: Mesh, axis_names=("data", "model"), *,
@@ -404,8 +403,8 @@ def build_distributed(n: int, mesh: Mesh, axis_names=("data", "model"), *,
         ar, ai = fft_ex.fft_cols(ar, ai, impl=impl, interpret=interpret,
                                  layout=layout)
         # ar: (rows, n1), row j = global i2 row0 + j, cols = o1
-        i2g = row0.astype(jnp.uint32) + jnp.arange(rows, dtype=jnp.uint32)
-        tw_r, tw_i = _twiddle(i2g, jnp.arange(n1, dtype=jnp.uint32), n)
+        i2g = row0.astype(jnp.int32) + jnp.arange(rows, dtype=jnp.int32)
+        tw_r, tw_i = _twiddle(i2g, jnp.arange(n1, dtype=jnp.int32), n)
         return ar * tw_r - ai * tw_i, ar * tw_i + ai * tw_r
 
     def pass2(br, bi, out_major, col_offset=0, ncols=None):

@@ -32,50 +32,44 @@ from __future__ import annotations
 
 import math
 
-import jax
 import jax.numpy as jnp
 
 from repro.kernels.fft import plan as fft_plan
 from repro.kernels.fft import ref as fft_ref
-from repro.kernels.fft.matfft import (matfft, matfft_cols, rfft_leaf,
-                                      rfft_pack_leaf,
+from repro.kernels.fft.matfft import (matfft, matfft_cols,
+                                      resolve_interpret, rfft_leaf,
+                                      rfft_pack_leaf, split_twiddle,
                                       untangle_half_spectrum)
+from repro.kernels.fft.matfft import twiddle as twiddle_planes
 from repro.kernels.fft.stockham import stockham_fft
 
 Planar = tuple[jnp.ndarray, jnp.ndarray]
 
 
-def _auto_interpret(interpret: bool | None) -> bool:
-    if interpret is None:
-        return jax.default_backend() != "tpu"
-    return interpret
-
-
-def _leaf(xr, xi, impl: str, interpret: bool, epilogue=None, batch_tile=None):
+def _leaf(xr, xi, impl: str, interpret: bool, twiddle=None,
+          batch_tile=None):
+    """One row-major leaf pass; ``twiddle`` is matfft's ``global_twiddle``
+    (fused in the kernel for matfft, an elementwise multiply otherwise)."""
     if impl == "matfft":
-        return matfft(xr, xi, epilogue=epilogue, batch_tile=batch_tile,
+        return matfft(xr, xi, global_twiddle=twiddle, batch_tile=batch_tile,
                       interpret=interpret)
     if impl == "stockham":
-        if epilogue is not None:
-            yr, yi = stockham_fft(xr, xi, batch_tile=batch_tile,
-                                  interpret=interpret)
-            er, ei = epilogue
-            period = er.shape[0]
-            rows = yr.shape[0]
-            er = jnp.tile(er, (rows // period, 1))
-            ei = jnp.tile(ei, (rows // period, 1))
-            return yr * er - yi * ei, yr * ei + yi * er
-        return stockham_fft(xr, xi, batch_tile=batch_tile, interpret=interpret)
-    if impl == "ref":
+        yr, yi = stockham_fft(xr, xi, batch_tile=batch_tile,
+                              interpret=interpret)
+    elif impl == "ref":
         yr, yi = fft_ref.fft_ref(xr, xi)
-        if epilogue is not None:
-            er, ei = epilogue
-            period = er.shape[0]
-            er = jnp.tile(er, (yr.shape[0] // period, 1))
-            ei = jnp.tile(ei, (yr.shape[0] // period, 1))
-            return yr * er - yi * ei, yr * ei + yi * er
+    else:
+        raise ValueError(f"unknown fft impl {impl!r}")
+    if twiddle is None:
         return yr, yi
-    raise ValueError(f"unknown fft impl {impl!r}")
+    n_global, row_off, period = split_twiddle(twiddle)
+    row = (jnp.asarray(row_off, jnp.int32).reshape(-1)[0]
+           + jnp.arange(yr.shape[0], dtype=jnp.int32)[:, None])
+    if period:
+        row = row & (period - 1)
+    tr, ti = twiddle_planes(row, jnp.arange(yr.shape[1], dtype=jnp.int32),
+                            n_global)
+    return yr * tr - yi * ti, yr * ti + yi * tr
 
 
 # ---------------------------------------------------------------------------
@@ -85,8 +79,7 @@ def _leaf(xr, xi, impl: str, interpret: bool, epilogue=None, batch_tile=None):
 
 
 def axis_pass(xr: jnp.ndarray, xi: jnp.ndarray, view, *,
-              out_major: str = "row",
-              epilogue: tuple | None = None, global_twiddle=None,
+              out_major: str = "row", global_twiddle=None,
               impl: str = "matfft", interpret: bool | None = None,
               col_tile: int | None = None, col_offset: int = 0,
               ncols: int | None = None, layout: str = "zero_copy") -> Planar:
@@ -99,9 +92,8 @@ def axis_pass(xr: jnp.ndarray, xi: jnp.ndarray, view, *,
     column order, i.e. the transformed axis stays where it was, which is
     what keeps a chain of passes transpose-free in HBM.
 
-    ``epilogue`` is a planar (C, L) table multiplied into output row
-    (b, c) (the four-step's outer twiddle); ``global_twiddle`` is the
-    distributed on-the-fly variant. ``col_offset``/``ncols`` select an
+    ``global_twiddle`` (see `matfft.matfft`) multiplies output row (b, c)
+    by the four-step's outer twiddle. ``col_offset``/``ncols`` select an
     aligned column slab fetched in place from the full operand (the
     overlapped exchange engines' slab reads).
 
@@ -109,12 +101,6 @@ def axis_pass(xr: jnp.ndarray, xi: jnp.ndarray, view, *,
     kernel (`matfft_cols`); anything else falls back to a materialized
     transpose around the row-major leaf (the measured "copy" baseline).
     """
-    if epilogue is not None and global_twiddle is not None:
-        # matfft_cols asserts this deep in the kernel; the transpose
-        # fallback used to silently drop the twiddle — fail loudly so the
-        # two layouts can never diverge on a combined call
-        raise ValueError(
-            "axis_pass: epilogue and global_twiddle are mutually exclusive")
     B, L, C = view
     xr3 = xr.reshape(B, L, C)
     xi3 = xi.reshape(B, L, C)
@@ -122,27 +108,19 @@ def axis_pass(xr: jnp.ndarray, xi: jnp.ndarray, view, *,
     if (layout == "zero_copy" and impl == "matfft" and L > 1
             and fft_plan.is_pow2(C) and fft_plan.is_pow2(nc)
             and fft_plan.make_plan(L).levels == 1):
-        return matfft_cols(xr3, xi3, out_major=out_major, epilogue=epilogue,
+        return matfft_cols(xr3, xi3, out_major=out_major,
                            global_twiddle=global_twiddle, col_tile=col_tile,
                            col_offset=col_offset, ncols=nc,
-                           interpret=_auto_interpret(interpret))
+                           interpret=resolve_interpret(interpret))
     # fallback: materialize the transpose; columns become batch rows
     if col_offset or nc != C:
         xr3 = xr3[:, :, col_offset:col_offset + nc]
         xi3 = xi3[:, :, col_offset:col_offset + nc]
     xrt = xr3.swapaxes(1, 2).reshape(B * nc, L)
     xit = xi3.swapaxes(1, 2).reshape(B * nc, L)
-    if epilogue is not None:
-        er, ei = epilogue
-        er = jnp.tile(er[col_offset:col_offset + nc], (B, 1))
-        ei = jnp.tile(ei[col_offset:col_offset + nc], (B, 1))
-        yr, yi = fft(xrt, xit, impl=impl, interpret=interpret,
-                     batch_tile=col_tile, layout=layout)
-        yr, yi = yr * er - yi * ei, yr * ei + yi * er
-    else:
-        yr, yi = fft(xrt, xit, impl=impl, interpret=interpret,
-                     batch_tile=col_tile, global_twiddle=global_twiddle,
-                     layout=layout)
+    yr, yi = fft(xrt, xit, impl=impl, interpret=interpret,
+                 batch_tile=col_tile, global_twiddle=global_twiddle,
+                 layout=layout)
     if out_major == "col":
         return (yr.reshape(B, nc, L).swapaxes(1, 2),
                 yi.reshape(B, nc, L).swapaxes(1, 2))
@@ -164,14 +142,11 @@ def four_step_zero_copy(xr: jnp.ndarray, xi: jnp.ndarray, n1: int, n2: int,
     """
     rows, n = xr.shape
     assert n == n1 * n2
-    # T[o1, i2] -> (i2, o1): pass-1 output row (b, i2) is multiplied by
-    # T^T[i2, :] — period n2 == the pass-1 column count, no O(batch*n)
-    # twiddle tensor.
-    tr, ti = fft_plan.twiddle_table(n1, n2, n)
-    epi = (jnp.asarray(tr.T.copy()), jnp.asarray(ti.T.copy()))
-
-    ar, ai = axis_pass(xr, xi, (rows, n1, n2), out_major="row", epilogue=epi,
-                       impl=impl, col_tile=col_tile,
+    # pass-1 output row (b, i2) gets W_N^{i2 * o1}: the kernel computes it
+    # in its epilogue (row index mod n2), so no twiddle table exists
+    ar, ai = axis_pass(xr, xi, (rows, n1, n2), out_major="row",
+                       global_twiddle=(n, 0, n2), impl=impl,
+                       col_tile=col_tile,
                        interpret=interpret)  # (rows*n2, n1), row (b, i2)
     cr, ci = axis_pass(ar, ai, (rows, n2, n1), out_major="col", impl=impl,
                        col_tile=col_tile,
@@ -190,7 +165,7 @@ def fft(xr: jnp.ndarray, xi: jnp.ndarray, *, impl: str = "matfft",
     """
     if layout not in ("zero_copy", "copy"):
         raise ValueError(f"unknown layout {layout!r}")
-    interpret = _auto_interpret(interpret)
+    interpret = resolve_interpret(interpret)
     batch_shape, n = xr.shape[:-1], xr.shape[-1]
     if n == 1:
         return xr, xi
@@ -203,14 +178,8 @@ def fft(xr: jnp.ndarray, xi: jnp.ndarray, *, impl: str = "matfft",
 
     p = fft_plan.make_plan(n)
     if p.levels == 1:
-        if global_twiddle is not None and impl == "matfft":
-            # fused distributed twiddle (core/fft/distributed.py): computed
-            # on the fly in the kernel epilogue, no HBM table
-            yr, yi = matfft(xr2, xi2, global_twiddle=global_twiddle,
-                            batch_tile=batch_tile,
-                            interpret=_auto_interpret(interpret))
-        else:
-            yr, yi = _leaf(xr2, xi2, impl, interpret, batch_tile=batch_tile)
+        yr, yi = _leaf(xr2, xi2, impl, interpret, twiddle=global_twiddle,
+                       batch_tile=batch_tile)
     else:
         if global_twiddle is not None:
             raise ValueError("global_twiddle requires a single-level plan")
@@ -229,9 +198,8 @@ def _four_step(xr, xi, n1: int, n2: int, impl: str, interpret: bool,
 
     layout="copy": the legacy path — three reshape+swapaxes transposes
     around two row-major leaf passes, each a full HBM round-trip. Pass 1
-    still fuses the outer twiddle W_N^{o1*i2} into the leaf epilogue: the
-    epilogue operand is just the (n2, n1) table indexed periodically — no
-    O(batch*n) twiddle tensor is ever materialized.
+    still fuses the outer twiddle W_N^{o1*i2} into the leaf epilogue,
+    computed on the fly: no twiddle table exists.
     """
     rows, n = xr.shape
     assert n == n1 * n2
@@ -240,16 +208,12 @@ def _four_step(xr, xi, n1: int, n2: int, impl: str, interpret: bool,
         return four_step_zero_copy(xr, xi, n1, n2, impl=impl,
                                    col_tile=batch_tile, interpret=interpret)
 
-    # T[o1, i2] -> transpose to (i2, o1): row (b, i2) of pass-1 output gets
-    # multiplied by T^T[i2, :]. Periodic with period n2 in the row index.
-    tr, ti = fft_plan.twiddle_table(n1, n2, n)
-    epi = (jnp.asarray(tr.T.copy()), jnp.asarray(ti.T.copy()))
-
     def to_cols(a):  # (rows, n1*n2) -> (rows*n2, n1)
         return a.reshape(rows, n1, n2).swapaxes(1, 2).reshape(rows * n2, n1)
 
+    # row (b, i2) of the pass-1 output gets W_N^{i2 * o1} (row mod n2)
     ar, ai = _leaf(to_cols(xr), to_cols(xi), impl, interpret,
-                   epilogue=epi, batch_tile=batch_tile)
+                   twiddle=(n, 0, n2), batch_tile=batch_tile)
 
     def to_rows(a):  # (rows*n2, n1) -> (rows*n1, n2)
         return a.reshape(rows, n2, n1).swapaxes(1, 2).reshape(rows * n1, n2)
@@ -308,11 +272,11 @@ def rfft(x: jnp.ndarray, *, impl: str = "matfft",
          layout: str = "zero_copy") -> Planar:
     """Real-input FFT; returns planar one-sided spectrum (n//2 + 1 bins).
 
-    Fast path (impl="matfft", n >= 4): n real samples are packed as n/2
-    complex points by a FREE reshape, one half-length transform runs on the
-    MXU, and the conjugate-symmetric spectrum is untangled in the kernel
-    epilogue (leaf sizes) or a vectorized jnp epilogue (level-1 sizes) —
-    ~half the flops and HBM bytes of fft()+slice (DESIGN.md §4).
+    Fast path (impl="matfft", n >= 4): at leaf sizes one kernel reads the
+    real rows and writes only the one-sided spectrum; above, n reals are
+    packed as n/2 complex points, one half-length transform runs, and a
+    vectorized jnp epilogue untangles the spectrum — ~half the HBM bytes
+    of fft()+slice (DESIGN.md §4).
     """
     n = x.shape[-1]
     x = x.astype(jnp.float32)
@@ -330,7 +294,7 @@ def rfft(x: jnp.ndarray, *, impl: str = "matfft",
     x2 = x.reshape(rows, n)
     if fft_plan.make_plan(m).levels == 1:
         yr, yi = rfft_leaf(x2, batch_tile=batch_tile,
-                           interpret=_auto_interpret(interpret))
+                           interpret=resolve_interpret(interpret))
     else:
         # level-1: the untangle can't live inside one leaf tile (bin o
         # pairs with m - o, a different o1-block), so pack + untangle run
@@ -393,7 +357,7 @@ def rfft_pack_pass(x2: jnp.ndarray, n_last: int, *, impl: str = "matfft",
     m = n_last // 2
     if fft_plan.make_plan(m).levels == 1:
         return rfft_pack_leaf(x2, batch_tile=batch_tile,
-                              interpret=_auto_interpret(interpret))
+                              interpret=resolve_interpret(interpret))
     # n_last > 2*MAX_LEAF: the half transform is level-1; pack on the
     # host (one extra round trip, counted by plan.rfftn_hbm_bytes)
     z = x2.reshape(x2.shape[0], m, 2)

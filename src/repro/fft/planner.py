@@ -35,6 +35,7 @@ from repro.fft import executors
 from repro.fft import spec as spec_mod
 from repro.fft.spec import FftSpec
 from repro.kernels.fft import plan as kplan
+from repro.kernels.fft.matfft import resolve_interpret
 
 _F32 = 4  # bytes per planar float32 element
 
@@ -789,8 +790,11 @@ def plan(kind: str = "c2c", *, n: int | None = None, shape=None,
 
     # resolve interpret-mode auto-detection BEFORE the spec is built, so
     # interpret=None and the equivalent explicit bool key the same plan
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
+    if impl == "stockham" and jax.default_backend() == "tpu":
+        raise ValueError(
+            "impl='stockham' is an interpret-mode baseline whose kernel does "
+            "not lower on a TPU; use impl='matfft' (or 'ref')")
 
     def _degrade(reason: str):
         """Graceful-degradation chain: shrunk healthy mesh, then local.

@@ -18,6 +18,8 @@ into test.sh and the CI workflow as the facade's cheap end-to-end gate.
 import os
 import sys
 
+# a CPU smoke on forced host devices: never claim an attached accelerator
+os.environ["JAX_PLATFORMS"] = "cpu"
 _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (

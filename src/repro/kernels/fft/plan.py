@@ -22,9 +22,12 @@ from dataclasses import dataclass
 
 import numpy as np
 
-# Maximum transform length handled directly by one kernel invocation
-# (a (batch_tile x N) tile plus two DFT matrices must fit in ~16MB VMEM).
+# Maximum transform length handled directly by one kernel invocation.
 MAX_LEAF = 16384
+# Lane width of a TPU vreg. Mosaic splits a row's lane axis in VMEM only
+# into pieces that are whole multiples of it, and a column block's minor
+# dim must be a multiple of it (or the array's full width).
+LANES = 128
 
 
 def is_pow2(n: int) -> bool:
@@ -50,6 +53,12 @@ def split_pow2(n: int, max_leaf: int = MAX_LEAF) -> tuple[int, int]:
     return n1, n2
 
 
+def leaf_split(n: int) -> tuple[int, int]:
+    """Split a leaf length n = q * n2 for the in-VMEM four-step: q lane
+    slabs of n2 = LANES samples (one slab of n below LANES)."""
+    return (n // LANES, LANES) if n >= LANES else (1, n)
+
+
 @functools.lru_cache(maxsize=None)
 def dft_matrix(n: int) -> tuple[np.ndarray, np.ndarray]:
     """Planar (re, im) forward DFT matrix W[i, o] = exp(-2j*pi*i*o/n), f32."""
@@ -68,16 +77,77 @@ def twiddle_table(n1: int, n2: int, n: int) -> tuple[np.ndarray, np.ndarray]:
 
 
 @functools.lru_cache(maxsize=None)
+def slab_twiddles(q: int) -> tuple[np.ndarray, np.ndarray]:
+    """Planar radix-2 stage twiddles of the q-point DFT across a leaf
+    tile's lane slabs, shape (q, 1, LANES): row m + k (m = 1, 2, 4, ...,
+    k < m) holds W_{2m}^k on every lane; row 0 is unused."""
+    ang = np.zeros(q)
+    m = 1
+    while m < q:
+        ang[m:2 * m] = -math.pi * np.arange(m) / m
+        m *= 2
+    tile = np.ones((1, 1, LANES))
+    return ((np.cos(ang)[:, None, None] * tile).astype(np.float32),
+            (np.sin(ang)[:, None, None] * tile).astype(np.float32))
+
+
+@functools.lru_cache(maxsize=None)
 def rfft_twiddle(n: int) -> tuple[np.ndarray, np.ndarray]:
     """Planar packing twiddle v[k] = exp(-2j*pi*k/n), shape (1, n//2).
 
     Combines the even/odd sub-spectra of the half-length packed transform
-    into the one-sided real-input spectrum (matfft._rfft_kernel).
+    into the one-sided real-input spectrum (matfft.untangle_half_spectrum).
     """
     k = np.arange(n // 2, dtype=np.float64)
     ang = -2.0 * math.pi * k / n
     return (np.cos(ang).astype(np.float32).reshape(1, -1),
             np.sin(ang).astype(np.float32).reshape(1, -1))
+
+
+@functools.lru_cache(maxsize=None)
+def real_dft_matrix(n: int, packed: bool) -> tuple[np.ndarray, np.ndarray]:
+    """Planar (n, n//2) matrix taking n real samples to half a spectrum.
+
+    ``packed=False``: the one-sided bins k < n/2, F[s, k] = W_n^{s*k}.
+    ``packed=True``: the half-length transform of the even/odd packing
+    z[j] = x[2j] + i*x[2j+1], F[s, k] = W_{n/2}^{(s//2)*k} * i^(s%2).
+    """
+    s = np.arange(n)
+    k = np.arange(n // 2)
+    if packed:
+        ang = -2.0 * math.pi * np.outer(s // 2, k) / (n // 2)
+        w = np.exp(1j * ang) * (1j ** (s % 2))[:, None]
+    else:
+        w = np.exp(-2j * math.pi * np.outer(s, k) / n)
+    return w.real.astype(np.float32), w.imag.astype(np.float32)
+
+
+@functools.lru_cache(maxsize=None)
+def real_four_step_tables(n: int, packed: bool):
+    """Tables of the in-VMEM four-step over n real samples (rfft leaf).
+
+    Sample s = c*LANES + l (c < q = n/LANES) and bin k = o2*q + o1, so
+    stage 1 is the q-point DFT across the lane slabs. Returned: the
+    twiddle T[o1, l] (q, LANES) and the stage-2 matrix G[l, o2]
+    (LANES, LANES), of which bins o2 < LANES/2 (k < n/2) are kept.
+
+    ``packed=False`` (one-sided bins): T = W_n^{o1*l}, G = W_LANES^{l*o2}.
+    ``packed=True`` (DFT_{n/2} of the even/odd packing):
+    T = W_{n/2}^{o1*(l//2)}, G = W_{LANES/2}^{(l//2)*o2} * i^(l%2).
+    """
+    q = n // LANES
+    o1 = np.arange(q)
+    ln = np.arange(LANES)
+    if packed:
+        t = np.exp(-2j * math.pi * np.outer(o1, ln // 2) / (n // 2))
+        g = (np.exp(-2j * math.pi * np.outer(ln // 2, ln) / (LANES // 2))
+             * (1j ** (ln % 2))[:, None])
+    else:
+        t = np.exp(-2j * math.pi * np.outer(o1, ln) / n)
+        g = np.exp(-2j * math.pi * np.outer(ln, ln) / LANES)
+    f32 = np.float32
+    return (t.real.astype(f32), t.imag.astype(f32),
+            g.real.astype(f32), g.imag.astype(f32))
 
 
 @functools.lru_cache(maxsize=None)
@@ -140,7 +210,7 @@ class FftPlan:
         """Actual real MACs issued by the matmul formulation (per batch row)."""
         if self.levels == 1:
             return 4.0 * self.n * (self.n1 + self.n2)
-        f1, f2 = split_pow2(self.n1), split_pow2(self.n2)
+        f1, f2 = leaf_split(self.n1), leaf_split(self.n2)
         return 4.0 * self.n * (f1[0] + f1[1] + f2[0] + f2[1])
 
 
@@ -248,7 +318,7 @@ def rfftn_hbm_bytes(shape, max_leaf: int = MAX_LEAF) -> int:
 
 def make_plan(n: int, max_leaf: int = MAX_LEAF) -> FftPlan:
     if n <= max_leaf:
-        n1, n2 = (1, n) if n <= 2 else split_pow2(n, max_leaf)
+        n1, n2 = (1, n) if n <= 2 else leaf_split(n)
         return FftPlan(n=n, levels=1, n1=n1, n2=n2)
     n1, n2 = split_pow2(n, max_leaf)
     return FftPlan(n=n, levels=2, n1=n1, n2=n2)

@@ -11,9 +11,10 @@ Per-stage twiddles arrive packed in a single (n,) planar pair (see
 plan.stockham_twiddles); stage s slices its l = n >> (s+1) factors at a
 static offset, so the whole stage loop unrolls with static shapes.
 
-NOTE on layout: the (bt, 2, l, m) reshapes with small m are lane-hostile on
-real Mosaic lowering; this kernel exists as the measured baseline, not the
-production path.
+NOTE on layout: the (bt, 2, l, m) reshapes with small m split the lane
+axis into pieces narrower than 128, which Mosaic does not lower. So this
+kernel runs in interpret mode only, as the CPU baseline; on a TPU it
+raises rather than run interpreted.
 """
 
 from __future__ import annotations
@@ -23,6 +24,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.fft import plan as fft_plan
+from repro.kernels.fft.matfft import resolve_interpret
 
 
 def _stockham_kernel(xr_ref, xi_ref, twr_ref, twi_ref, outr_ref, outi_ref,
@@ -55,8 +57,16 @@ def _stockham_kernel(xr_ref, xi_ref, twr_ref, twi_ref, outr_ref, outi_ref,
 
 def stockham_fft(xr: jnp.ndarray, xi: jnp.ndarray, *,
                  batch_tile: int | None = None,
-                 interpret: bool = True) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Batched forward DFT along the last axis via radix-2 Stockham stages."""
+                 interpret: bool | None = None
+                 ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Batched forward DFT along the last axis via radix-2 Stockham stages.
+
+    Interpret mode only (``interpret=None`` resolves to it off-TPU): the
+    stage reshapes do not lower on a TPU, so a compiled call raises.
+    """
+    if not resolve_interpret(interpret):
+        raise NotImplementedError(
+            "the stockham kernel does not lower on a TPU; use impl='matfft'")
     if xr.ndim != 2:
         raise ValueError(f"stockham_fft expects 2-D (rows, n), got {xr.shape}")
     rows, n = xr.shape

@@ -1,4 +1,6 @@
 import os
+# compile-only on forced host devices: never claim an attached accelerator
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("REPRO_EXTRA_XLA_FLAGS", "") +
                            " --xla_force_host_platform_device_count=512").strip()
 

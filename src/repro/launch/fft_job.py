@@ -35,6 +35,7 @@ from repro.core.pipeline import (BlockStore, JobConfig, MapOnlyJob,
                                  segments_of_block)
 from repro.core.pipeline.records import segment_block_bytes
 import repro.fft as fft_api
+from repro.launch.compile_cache import enable_compile_cache
 
 
 class _TimedStore:
@@ -278,6 +279,7 @@ def main(argv=None):
                     help="wisdom file for --tune (default "
                          "~/.cache/repro_fft/wisdom.json)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.out_of_core:
         print(json.dumps(run_out_of_core(args), indent=1))

@@ -21,6 +21,7 @@ import time
 from repro.core.resilience import (FaultInjector, FaultPlan, RetryPolicy,
                                    event_stats, events)
 import repro.fft as fft_api
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve import FftService
 from repro.serve import loadgen
 from repro.serve.fft_service import SHED_POLICIES
@@ -73,6 +74,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0,
                     help="workload seed (request mix + operand content)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     num_requests = args.requests
     if args.qps and args.duration:

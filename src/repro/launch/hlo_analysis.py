@@ -23,24 +23,10 @@ from collections import defaultdict
 
 
 def cost_analysis_dict(raw) -> dict:
-    """Normalize ``compiled.cost_analysis()`` across JAX versions.
+    """``compiled.cost_analysis()`` as a flat ``{"flops": ...,
+    "bytes accessed": ...}`` mapping (empty when the backend gives none)."""
+    return dict(raw or {})
 
-    Depending on the release it returns a dict, a one-element list of dicts
-    (one per executable program), or None. Callers always want a flat
-    ``{"flops": ..., "bytes accessed": ...}`` mapping.
-    """
-    if raw is None:
-        return {}
-    if isinstance(raw, dict):
-        return raw
-    if isinstance(raw, (list, tuple)):
-        out: dict = {}
-        for entry in raw:
-            for k, v in dict(entry).items():
-                out[k] = out.get(k, 0.0) + v if isinstance(v, (int, float)) \
-                    else v
-        return out
-    return dict(raw)
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3": 1, "f8e5m2": 1,

@@ -50,21 +50,20 @@ def test_four_step_ref_algebra(rng):
     assert _rel_err(yr, yi, wr, wi) < 5e-6
 
 
-def test_epilogue_fusion_matches_unfused(rng):
-    """Fused twiddle epilogue == separate multiply (the HBM-saving path)."""
-    rows, n, period = 32, 256, 8
+@pytest.mark.parametrize("n", [256, 1024])
+def test_epilogue_fusion_matches_unfused(rng, n):
+    """Fused on-the-fly twiddle epilogue == separate multiply (the
+    HBM-saving path): row r, bin k gets W_N^{((3 + r) mod 8) * k}."""
+    rows, period, n_global, row_off = 32, 8, 8 * n, 3
     xr = rng.standard_normal((rows, n)).astype(np.float32)
     xi = rng.standard_normal((rows, n)).astype(np.float32)
-    er = rng.standard_normal((period, n)).astype(np.float32)
-    ei = rng.standard_normal((period, n)).astype(np.float32)
     fr, fi = matfft(jnp.asarray(xr), jnp.asarray(xi),
-                    epilogue=(jnp.asarray(er), jnp.asarray(ei)))
+                    global_twiddle=(n_global, jnp.int32(row_off), period))
     yr, yi = matfft(jnp.asarray(xr), jnp.asarray(xi))
-    tr = np.tile(er, (rows // period, 1))
-    ti = np.tile(ei, (rows // period, 1))
-    wr = np.asarray(yr) * tr - np.asarray(yi) * ti
-    wi = np.asarray(yr) * ti + np.asarray(yi) * tr
-    assert _rel_err(np.asarray(fr), np.asarray(fi), wr, wi) < 5e-6
+    g = (row_off + np.arange(rows)) % period
+    t = np.exp(-2j * np.pi * np.outer(g, np.arange(n)) / n_global)
+    w = (np.asarray(yr) + 1j * np.asarray(yi)) * t
+    assert _rel_err(np.asarray(fr), np.asarray(fi), w.real, w.imag) < 5e-6
 
 
 def test_dtype_is_float32(rng):

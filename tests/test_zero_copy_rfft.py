@@ -102,24 +102,22 @@ def test_fft_cols_matches_transposed_fft(rng):
 
 @pytest.mark.parametrize("out_major", ["row", "col"])
 def test_matfft_cols_epilogue_and_layouts(rng, out_major):
-    """Column kernel with fused epilogue == transpose + fft + multiply."""
+    """Column kernel with the fused level-1 twiddle epilogue (row (b, c)
+    gets W_{L*C}^{c * k}) == transpose + fft + multiply."""
     B, L, C = 2, 256, 16
     xr = rng.standard_normal((B, L, C)).astype(np.float32)
     xi = rng.standard_normal((B, L, C)).astype(np.float32)
-    er = rng.standard_normal((C, L)).astype(np.float32)
-    ei = rng.standard_normal((C, L)).astype(np.float32)
     yr, yi = matfft_cols(jnp.asarray(xr), jnp.asarray(xi),
-                         out_major=out_major,
-                         epilogue=(jnp.asarray(er), jnp.asarray(ei)))
+                         out_major=out_major, global_twiddle=(L * C, 0, C))
     # oracle: batched fft of the transposed columns, then the row multiply
     cols_r = np.swapaxes(xr, 1, 2).reshape(B * C, L)
     cols_i = np.swapaxes(xi, 1, 2).reshape(B * C, L)
     fr, fi = (np.asarray(a) for a in
               ops.fft(jnp.asarray(cols_r), jnp.asarray(cols_i)))
-    tr = np.tile(er, (B, 1))
-    ti = np.tile(ei, (B, 1))
-    wr = fr * tr - fi * ti
-    wi = fr * ti + fi * tr
+    t = np.tile(np.exp(-2j * np.pi * np.outer(np.arange(C), np.arange(L))
+                       / (L * C)), (B, 1))
+    w = (fr + 1j * fi) * t
+    wr, wi = w.real, w.imag
     if out_major == "col":
         wr = np.swapaxes(wr.reshape(B, C, L), 1, 2)
         wi = np.swapaxes(wi.reshape(B, C, L), 1, 2)
