@@ -1,0 +1,15 @@
+"""Share of the traced window in which the stream's dispatcher waited on
+the in-flight window: the union of the program's
+``fft.stream.wait_inflight`` spans over the window. High when the device
+or the writers set the pace. None where the program writes no such
+span."""
+
+from bench import programspans
+
+
+def read(ctx):
+    spans = programspans.of(ctx)
+    if spans is None:
+        return None
+    return programspans.union_pct(spans, "fft.stream.wait_inflight",
+                                  ctx.trace.window)

@@ -20,9 +20,9 @@ records the trajectory in BENCH_pipeline.json:
     dispatch serializes on the device anyway).
 
 Per-mode metrics: throughput (input MB/s of job wall), per-stage clock
-totals (read/h2d/compute/d2h/write), ``overlap_efficiency`` = max(stage
-totals)/wall (1.0 = wall collapsed onto the slowest stage, a perfectly
-hidden pipeline) and ``overlap_x`` = sum(stage totals)/wall (> 1 proves
+totals (read/gather/launch/device_wait/d2h/verify/write),
+``overlap_efficiency`` = max(stage totals)/wall (1.0 = wall collapsed onto
+the slowest stage, a perfectly hidden pipeline) and ``overlap_x`` = sum(stage totals)/wall (> 1 proves
 compute and I/O genuinely ran concurrently: wall < sum of stage times).
 Outputs of all modes must be bitwise identical — coalesced batches and the
 remainder tail must not change a single bit.

@@ -186,10 +186,14 @@ class JobStats:
     speculative_launches: int = 0
     speculative_wins: int = 0
     wall_s: float = 0.0
-    task_seconds: list[float] = field(default_factory=list)
-    # streaming path: per-stage clock totals (read/h2d/compute/d2h/write)
-    # and coalescing counters; empty/zero on the serial path
+    # streaming path: per-stage clock totals, each the summed duration of
+    # the stage's ``fft.stream.<stage>`` spans (stream.STAGES), and
+    # coalescing counters; empty/zero on the serial path
     stage_s: dict[str, float] = field(default_factory=dict)
+    # streaming path: seconds the dispatcher waited, on the decoded queue
+    # ("decoded": readers set the pace) and on the in-flight window
+    # ("inflight": the device or the writers do); not stage work
+    wait_s: dict[str, float] = field(default_factory=dict)
     batches: int = 0
     coalesced_blocks: int = 0
     # blocks whose retry budget was exhausted this run: one structured
@@ -310,7 +314,6 @@ class MapOnlyJob:
                         _, dt = fut.result()
                         completed.add(i)
                         self._done_latencies.append(dt)
-                        self.stats.task_seconds.append(dt)
                         self.stats.blocks_done += 1
                         if is_spec:
                             self.stats.speculative_wins += 1

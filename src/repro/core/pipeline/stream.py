@@ -8,26 +8,36 @@ This module restructures the job as a staged stream (EFFT, arXiv:1409.5757
 — double-buffered streaming hides disk/transfer behind compute; and
 arXiv:2202.12756 — batch many transforms per launch):
 
-  read     reader threads: block I/O + crc verify + zero-copy decode
-           (strided views over the block bytes). The bounded decoded
-           queue is the prefetch back-pressure — readers block when the
-           device side lags, capping host memory however far I/O could
-           run ahead.
-  h2d      the single dispatcher coalesces up to `coalesce` same-shaped
-           blocks into ONE device batch (the `cufftPlanMany` amortization:
-           one cached plan at batch coalesce x segments_per_block, plus one
-           remainder-tail plan), gathering them into reusable preallocated
-           staging buffers (`StagingPool`) that feed the async launch.
-  compute  `plan.execute_async` — unrealized device arrays, NO
-           block_until_ready anywhere in the hot path. The dispatcher keeps
-           at most `inflight` launched batches outstanding (a semaphore
-           released by the writeback stage once a batch's D2H completes):
-           when the window is full, dispatch stalls until the OLDEST
-           in-flight batch realizes — that window boundary is the only
-           sync point in the pipeline.
-  d2h      writeback workers realize device results (np.asarray) while the
-           dispatcher is already launching later batches.
-  write    same workers: per-block encode + atomic offset-named writes.
+  read         reader threads: block I/O + crc verify + zero-copy decode
+               (strided views over the block bytes). The bounded decoded
+               queue is the prefetch back-pressure — readers block when
+               the device side lags, capping host memory however far I/O
+               could run ahead.
+  gather       the single dispatcher coalesces up to `coalesce`
+               same-shaped blocks into ONE device batch (the
+               `cufftPlanMany` amortization: one cached plan at batch
+               coalesce x segments_per_block, plus one remainder-tail
+               plan), gathering them on the host into reusable
+               preallocated staging buffers (`StagingPool`).
+  launch       `plan.execute_async`: the H2D copy of the staging buffers
+               and the enqueue, returning unrealized device arrays. The
+               dispatcher keeps at most `inflight` launched batches
+               outstanding (a semaphore released by the writeback stage
+               once a batch is back on the host): when the window is full,
+               dispatch stalls until the OLDEST in-flight batch realizes —
+               that window boundary is the only sync point in the pipeline.
+  device_wait  writeback workers wait for a batch's device work to end
+               while the dispatcher is already launching later batches;
+  d2h          then copy it back (np.asarray);
+  verify       then check the transform's invariants (ABFT, if on);
+  write        then encode each block and write it atomically, fsynced.
+
+Each stage is timed by its ``fft.stream.<stage>`` span (`repro.spans`),
+whose durations ``stats.stage_s[<stage>]`` sums. The dispatcher's waits
+are spans too, ``fft.stream.wait_decoded`` and ``fft.stream.wait_inflight``,
+summed in ``stats.wait_s``; each launch is inside ``fft.stream.dispatch``,
+whose ``blocks`` and ``queued`` attributes give the group's size and the
+decoded queue's depth at dispatch.
 
 Retry / speculation / manifest semantics match `MapOnlyJob`: every
 transition journaled (RUNNING at dispatch into the pipeline, DONE after
@@ -49,8 +59,10 @@ from dataclasses import dataclass
 from statistics import median
 from typing import Any, Callable
 
+import jax
 import numpy as np
 
+from repro import spans
 from repro.core.pipeline.blockstore import BlockStore
 from repro.core.pipeline.maponly import (DONE, FAILED, PENDING, RUNNING,
                                          JobConfig, JobStats, Manifest)
@@ -59,7 +71,10 @@ from repro.core.resilience import verify as abft
 from repro.core.resilience.faults import (corrupt_salt, maybe_fire,
                                           perturb_array)
 
-STAGES = ("read", "h2d", "compute", "d2h", "write")
+STAGES = ("read", "gather", "launch", "device_wait", "d2h", "verify",
+          "write")
+#: what the dispatcher waits on, keys of ``JobStats.wait_s``
+WAITS = ("decoded", "inflight")
 
 
 class _Stop(Exception):
@@ -134,10 +149,10 @@ class StreamTransform:
     """decode / launch / realize / encode hooks for `StreamExecutor`.
 
     ``launch`` must be asynchronous (return unrealized device values);
-    ``realize`` is the only place a sync may happen. Blocks whose ``key``
-    matches are coalesced into one ``launch`` group, so all hooks must be
-    thread-safe: decode runs on reader threads, launch on the dispatcher,
-    realize/encode on writeback workers.
+    ``wait`` and ``realize`` are the only places a sync may happen. Blocks
+    whose ``key`` matches are coalesced into one ``launch`` group, so all
+    hooks must be thread-safe: decode runs on reader threads, launch on
+    the dispatcher, wait/realize/encode on writeback workers.
     """
 
     def open(self, pool_capacity: int, stop: threading.Event) -> None:
@@ -147,12 +162,18 @@ class StreamTransform:
         raise NotImplementedError
 
     def gather(self, group: list[Decoded]):
-        """Host-side batch assembly (the h2d stage clock). After this
+        """Host-side batch assembly (the gather stage clock). After this
         returns, the group's staging buffers may be reused."""
         return group
 
     def launch(self, batch):
         raise NotImplementedError
+
+    def wait(self, handle) -> None:
+        """Block until the launched work is done (the device_wait stage
+        clock), so that ``realize`` times the copy alone. ``realize`` runs
+        after it even when it raises."""
+        jax.block_until_ready(handle)
 
     def realize(self, handle):
         raise NotImplementedError
@@ -224,6 +245,9 @@ class MapFnTransform(StreamTransform):
         if self._pool is None:  # transform used outside an executor
             return self.map_fn(d.arrays[0], d.index)
         return self._pool.submit(self.map_fn, d.arrays[0], d.index)
+
+    def wait(self, handle) -> None:
+        self.realize(handle)
 
     def realize(self, handle):
         if isinstance(handle, Future):
@@ -335,7 +359,7 @@ class SegmentFFTTransform(StreamTransform):
     def realize(self, handle):
         (yr, yi), batch = handle
         try:
-            return np.asarray(yr), np.asarray(yi)  # D2H: the window sync
+            return np.asarray(yr), np.asarray(yi)  # D2H, after wait
         finally:
             # async dispatch surfaces device errors HERE, so the release
             # must be unconditional or each transient failure leaks a set
@@ -374,7 +398,8 @@ class StreamExecutor:
 
     Shares `Manifest` + `JobStats` with `MapOnlyJob` so the pipelined path
     is a drop-in: same crash-restart, retry-budget and speculation
-    semantics, plus per-stage clocks in ``stats.stage_s``.
+    semantics, plus per-stage clocks in ``stats.stage_s`` and the
+    dispatcher's waits in ``stats.wait_s``.
     """
 
     def __init__(self, store: BlockStore, out_dir, transform: StreamTransform,
@@ -411,9 +436,15 @@ class StreamExecutor:
         self._first_started: dict[int, float] = {}
 
     # ------------------------------------------------------------------
-    def _add_stage(self, stage: str, dt: float) -> None:
-        with self._stats_lock:
-            self.stats.stage_s[stage] = self.stats.stage_s.get(stage, 0.) + dt
+    def _stage(self, stage: str):
+        """The ``fft.stream.<stage>`` span, summed in ``stage_s``."""
+        return spans.timed(f"fft.stream.{stage}", self.stats.stage_s, stage,
+                           self._stats_lock)
+
+    def _wait(self, what: str):
+        """The ``fft.stream.wait_<what>`` span, summed in ``wait_s``."""
+        return spans.timed(f"fft.stream.wait_{what}", self.stats.wait_s,
+                           what, self._stats_lock)
 
     def _put_decoded(self, item) -> None:
         while not self._stop.is_set():
@@ -433,11 +464,10 @@ class StreamExecutor:
             # retries clear the entry first, so their clock restarts
             self._started.setdefault(index, time.monotonic())
             try:
-                t0 = time.monotonic()
-                data = self.store.read_block(index)
-                maybe_fire(self._injector, "stream.decode", index)
-                d = self.transform.decode(data, index)
-                self._add_stage("read", time.monotonic() - t0)
+                with self._stage("read"):
+                    data = self.store.read_block(index)
+                    maybe_fire(self._injector, "stream.decode", index)
+                    d = self.transform.decode(data, index)
                 self._put_decoded(("ok", index, is_spec, d))
             except _Stop:
                 return
@@ -472,15 +502,24 @@ class StreamExecutor:
             row0 += d.rows
         return host[0] if len(host) == 1 else tuple(host)
 
+    def _realize(self, handle):
+        """A launched batch's result on the host. ``realize`` runs after a
+        failed ``wait`` too: it releases the batch's staging."""
+        try:
+            try:
+                with self._stage("device_wait"):
+                    self.transform.wait(handle)
+            finally:
+                with self._stage("d2h"):
+                    host = self.transform.realize(handle)
+        finally:
+            # the window boundary: oldest batch realized -> next launch
+            self._inflight.release()
+        return host
+
     def _writeback(self, handle, group: list[tuple[Decoded, bool]]) -> None:
         try:
-            t0 = time.monotonic()
-            try:
-                host = self.transform.realize(handle)
-            finally:
-                # the window boundary: oldest batch realized -> next launch
-                self._inflight.release()
-            self._add_stage("d2h", time.monotonic() - t0)
+            host = self._realize(handle)
             # fires only after realize: the staging set is back in the
             # pool (realize's finally), so an injected fault here cannot
             # leak pool capacity and starve the dispatcher
@@ -490,7 +529,8 @@ class StreamExecutor:
                 host = self._corrupt_host(host, group)
             # group invariant (abft checksum row): a failure here cannot
             # name the culprit, so the whole group quarantines and retries
-            self.transform.verify_group(host, [d for d, _ in group])
+            with self._stage("verify"):
+                self.transform.verify_group(host, [d for d, _ in group])
         except BaseException as e:
             for d, is_spec in group:
                 self._events.put(("err", d.index, is_spec, e))
@@ -499,14 +539,14 @@ class StreamExecutor:
         t_done = time.monotonic()
         for d, is_spec in group:
             try:
-                t0 = time.monotonic()
                 maybe_fire(self._injector, "stream.writeback", d.index)
                 # per-member invariant (Parseval): quarantines just this
                 # block back into the retry path — recompute-on-detect
-                self.transform.verify_member(host, row0, d)
-                out = self.transform.encode(host, row0, d)
-                self.store.write_output_block(self.out_dir, d.index, out)
-                self._add_stage("write", time.monotonic() - t0)
+                with self._stage("verify"):
+                    self.transform.verify_member(host, row0, d)
+                with self._stage("write"):
+                    out = self.transform.encode(host, row0, d)
+                    self.store.write_output_block(self.out_dir, d.index, out)
                 self._events.put(("done", d.index, is_spec, t_done))
             except BaseException as e:
                 self._events.put(("err", d.index, is_spec, e))
@@ -518,6 +558,8 @@ class StreamExecutor:
         t_start = time.monotonic()
         for s in STAGES:
             self.stats.stage_s.setdefault(s, 0.0)
+        for w in WAITS:
+            self.stats.wait_s.setdefault(w, 0.0)
 
         todo = self.manifest.pending()
         total_left = len(todo)
@@ -591,7 +633,6 @@ class StreamExecutor:
             total_left -= 1
             dt = t_done - self._started.get(i, t_done)
             latencies.append(dt)
-            self.stats.task_seconds.append(dt)
             self.stats.blocks_done += 1
             if is_spec:
                 self.stats.speculative_wins += 1
@@ -627,22 +668,25 @@ class StreamExecutor:
                     enqueue(i, True)
 
         def dispatch(group: list[tuple[Decoded, bool]]) -> None:
-            # h2d + launch; window back-pressure lives in the semaphore
-            while not self._inflight.acquire(timeout=cfg.poll_interval_s):
+            # gather + launch; window back-pressure lives in the semaphore
+            while True:
+                with self._wait("inflight"):
+                    if self._inflight.acquire(timeout=cfg.poll_interval_s):
+                        break
                 drain_events()  # keep completions flowing while we wait
                 if self._stop.is_set():
                     return
             batch = None
             try:
-                if self._injector is not None:
-                    self._injector.fire_group(
-                        "stream.launch", [d.index for d, _ in group])
-                t0 = time.monotonic()
-                batch = self.transform.gather([d for d, _ in group])
-                self._add_stage("h2d", time.monotonic() - t0)
-                t0 = time.monotonic()
-                handle = self.transform.launch(batch)
-                self._add_stage("compute", time.monotonic() - t0)
+                with spans.span("fft.stream.dispatch", blocks=len(group),
+                                queued=self._decoded.qsize()):
+                    if self._injector is not None:
+                        self._injector.fire_group(
+                            "stream.launch", [d.index for d, _ in group])
+                    with self._stage("gather"):
+                        batch = self.transform.gather([d for d, _ in group])
+                    with self._stage("launch"):
+                        handle = self.transform.launch(batch)
             except BaseException as e:
                 self._inflight.release()
                 if batch is not None:  # gathered but never launched
@@ -663,8 +707,9 @@ class StreamExecutor:
                 drain_events()
                 maybe_speculate()
                 try:
-                    kind, i, is_spec, payload = self._decoded.get(
-                        timeout=cfg.poll_interval_s)
+                    with self._wait("decoded"):
+                        kind, i, is_spec, payload = self._decoded.get(
+                            timeout=cfg.poll_interval_s)
                 except queue.Empty:
                     if group and decode_pending == 0:
                         dispatch(group)

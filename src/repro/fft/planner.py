@@ -25,12 +25,15 @@ An `ExecutablePlan` carries:
 
 from __future__ import annotations
 
+import contextlib
+import functools
 import math
 import threading
 
 import jax
 import jax.numpy as jnp
 
+from repro import spans
 from repro.fft import executors
 from repro.fft import spec as spec_mod
 from repro.fft.spec import FftSpec
@@ -165,6 +168,21 @@ class ExecutablePlan:
     @property
     def levels(self) -> int:
         return self.leaf.levels
+
+    @functools.cached_property
+    def tag(self) -> str:
+        """``<kind>_<shape>_b<rows>_<impl>``, with ``_<placement>`` when
+        not local and ``_<verify>`` when verified: the name of the plan's
+        jitted programs (``jit_counted_<tag>``, ``..._donated``,
+        ``..._inverse``) and of its ``fft.plan.launch`` spans."""
+        s = self.spec
+        tag = (f"{s.kind}_{'x'.join(map(str, s.shape))}_b{s.rows}"
+               f"_{s.impl}")
+        if s.placement != "local":
+            tag += f"_{s.placement}"
+        if s.verify != "off":
+            tag += f"_{s.verify}"
+        return tag
 
     @property
     def fused_untangle(self) -> bool:
@@ -468,6 +486,7 @@ class ExecutablePlan:
             self._traces["forward"] += 1
             return inner(*args)
 
+        self._name(counted)
         self._fwd_shardings = (in_shardings, out_shardings)
         if in_shardings is not None:
             jitted = jax.jit(counted, in_shardings=in_shardings,
@@ -495,6 +514,7 @@ class ExecutablePlan:
                         self._traces["forward"] += 1
                         return inner(*args)
 
+                    self._name(counted, "_donated")
                     in_sh, out_sh = self._fwd_shardings
                     donate = tuple(range(nargs))
                     if in_sh is not None:
@@ -554,7 +574,22 @@ class ExecutablePlan:
             self._traces["inverse"] += 1
             return inner(yr, yi)
 
+        self._name(counted, "_inverse")
         return inner, jax.jit(counted)
+
+    def _name(self, counted, variant: str = "") -> None:
+        """Name a jitted function so that its XLA module reads
+        ``jit_counted_<tag><variant>`` in a device trace."""
+        counted.__name__ = counted.__qualname__ = (
+            f"counted_{self.tag}{variant}")
+
+    def _launch_span(self, variant: str, *operands):
+        """The ``fft.plan.launch`` span of a host call: shape checks, jit
+        cache lookup, any H2D copy of host operands and the enqueue. None
+        inside a caller's trace."""
+        if _is_tracer(*operands):
+            return contextlib.nullcontext()
+        return spans.span("fft.plan.launch", plan=self.tag + variant)
 
     # ------------------------------------------------------------------
 
@@ -572,13 +607,14 @@ class ExecutablePlan:
             raise ValueError(
                 "execute() is for kind='c2c' plans; use execute_real(x) "
                 "on this r2c plan")
-        shape = self.spec.operand_shape
-        self._check_shape(xr.shape, shape, "execute")
-        self._check_shape(xi.shape, shape, "execute")
-        raw, jitted = self._forward()
-        if _is_tracer(xr, xi):
-            return raw(xr, xi)
-        return jitted(xr, xi)
+        with self._launch_span("", xr, xi):
+            shape = self.spec.operand_shape
+            self._check_shape(xr.shape, shape, "execute")
+            self._check_shape(xi.shape, shape, "execute")
+            raw, jitted = self._forward()
+            if _is_tracer(xr, xi):
+                return raw(xr, xi)
+            return jitted(xr, xi)
 
     def execute_real(self, x):
         """Forward r2c transform: real (*batch_shape, *shape) -> planar
@@ -587,11 +623,13 @@ class ExecutablePlan:
             raise ValueError(
                 "execute_real() is for kind='r2c' plans; use "
                 "execute(xr, xi) on this c2c plan")
-        self._check_shape(x.shape, self.spec.operand_shape, "execute_real")
-        raw, jitted = self._forward()
-        if _is_tracer(x):
-            return raw(x)
-        return jitted(x)
+        with self._launch_span("", x):
+            self._check_shape(x.shape, self.spec.operand_shape,
+                              "execute_real")
+            raw, jitted = self._forward()
+            if _is_tracer(x):
+                return raw(x)
+            return jitted(x)
 
     def execute_async(self, *operands, donate: bool = False):
         """Launch the forward transform WITHOUT synchronizing.
@@ -615,17 +653,19 @@ class ExecutablePlan:
             raise ValueError(
                 f"execute_async on a {self.spec.kind!r} plan takes "
                 f"{nargs} operand(s), got {len(operands)}")
-        shape = self.spec.operand_shape
-        for op in operands:
-            self._check_shape(op.shape, shape, "execute_async")
-        if _is_tracer(*operands):
-            return self._forward()[0](*operands)
-        if donate:
-            # backends without donation support ignore the hint (correct,
-            # no aliasing); any "donated buffers were not usable" warning
-            # is deduped per call site by the default warnings filter
-            return self._forward_donated()(*operands)
-        return self._forward()[1](*operands)
+        with self._launch_span("_donated" if donate else "", *operands):
+            shape = self.spec.operand_shape
+            for op in operands:
+                self._check_shape(op.shape, shape, "execute_async")
+            if _is_tracer(*operands):
+                return self._forward()[0](*operands)
+            if donate:
+                # backends without donation support ignore the hint
+                # (correct, no aliasing); any "donated buffers were not
+                # usable" warning is deduped per call site by the default
+                # warnings filter
+                return self._forward_donated()(*operands)
+            return self._forward()[1](*operands)
 
     def execute_inverse(self, yr, yi):
         """Inverse transform.
@@ -639,12 +679,13 @@ class ExecutablePlan:
             shape = s.operand_shape
         else:
             shape = (*s.batch_shape, *s.shape[:-1], s.shape[-1] // 2 + 1)
-        self._check_shape(yr.shape, shape, "execute_inverse")
-        self._check_shape(yi.shape, shape, "execute_inverse")
-        raw, jitted = self._inverse()
-        if _is_tracer(yr, yi):
-            return raw(yr, yi)
-        return jitted(yr, yi)
+        with self._launch_span("_inverse", yr, yi):
+            self._check_shape(yr.shape, shape, "execute_inverse")
+            self._check_shape(yi.shape, shape, "execute_inverse")
+            raw, jitted = self._inverse()
+            if _is_tracer(yr, yi):
+                return raw(yr, yi)
+            return jitted(yr, yi)
 
 
 # ---------------------------------------------------------------------------

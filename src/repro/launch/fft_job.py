@@ -14,8 +14,10 @@ modes over the same store:
     with ``--coalesce`` same-shaped blocks per device batch and an
     ``--inflight`` launch window, so device compute hides behind block I/O.
 
-Both report per-stage clocks (read/h2d/compute/d2h/write) instead of the
-old lumped io/fft split, plus the paper's Amdahl/runtime-model prediction.
+Both report per-stage clocks (read, launch, device_wait, d2h, verify,
+write; the stream adds gather), each the summed duration of the stage's
+``fft.stream.<stage>`` or ``fft.serial.<stage>`` spans, plus the paper's
+Amdahl/runtime-model prediction.
 """
 
 from __future__ import annotations
@@ -24,8 +26,10 @@ import argparse
 import json
 import threading
 import time
+from dataclasses import replace
 from pathlib import Path
 
+import jax
 import numpy as np
 import jax.numpy as jnp
 
@@ -35,7 +39,12 @@ from repro.core.pipeline import (BlockStore, JobConfig, MapOnlyJob,
                                  segments_of_block)
 from repro.core.pipeline.records import segment_block_bytes
 import repro.fft as fft_api
+from repro import spans
 from repro.launch.compile_cache import enable_compile_cache
+
+#: the serial path's stage clocks: the stream's (core/pipeline/stream.py)
+#: less ``gather``, which a one-block task does not do
+SERIAL_STAGES = ("read", "launch", "device_wait", "d2h", "verify", "write")
 
 
 class _TimedStore:
@@ -44,54 +53,49 @@ class _TimedStore:
     stream executor's (file I/O happens inside MapOnlyJob._attempt, out of
     map_fn's reach)."""
 
-    def __init__(self, store: BlockStore, add):
+    def __init__(self, store: BlockStore, stage):
         self._store = store
-        self._add = add
+        self._stage = stage
 
     def __getattr__(self, name):
         return getattr(self._store, name)
 
     def read_block(self, index: int, verify: bool = True) -> bytes:
-        t0 = time.monotonic()
-        data = self._store.read_block(index, verify)
-        self._add("read", t0)
-        return data
+        with self._stage("read"):
+            return self._store.read_block(index, verify)
 
     def write_output_block(self, out_dir, index: int, data) -> None:
-        t0 = time.monotonic()
-        self._store.write_output_block(out_dir, index, data)
-        self._add("write", t0)
+        with self._stage("write"):
+            self._store.write_output_block(out_dir, index, data)
 
 
-def serial_map_fn(fft_len: int, impl: str, add, verify: str = "off",
+def serial_map_fn(fft_len: int, impl: str, stage, verify: str = "off",
                   tune: bool = False, wisdom_path=None):
     """The synchronous per-block map task, with per-stage clocks.
 
-    Stage names match the stream executor's so the two paths are
-    comparable ("read"/"write" also accumulate the block file I/O, via
-    `_TimedStore`).
+    ``stage(name)`` is the context that times one stage. Stage names match
+    the stream executor's so the two paths are comparable ("read"/"write"
+    also accumulate the block file I/O, via `_TimedStore`).
     """
 
     def map_fn(data: bytes, idx: int) -> bytes:
-        t = time.monotonic()
-        re, im = segments_of_block(data, fft_len)
-        t = add("read", t)
-        re, im = jnp.asarray(re), jnp.asarray(im)
-        t = add("h2d", t)
-        # every same-shaped block hits the process-level plan cache: the
-        # jit'd callable is built once, the cufftPlanMany amortization
-        p = fft_api.plan(kind="c2c", n=fft_len,
-                         batch_shape=re.shape[:-1], impl=impl,
-                         verify=verify, tune=tune,
-                         wisdom_path=wisdom_path)
-        yr, yi = p.execute(re, im)
-        yr.block_until_ready()  # the serial path's per-block sync
-        t = add("compute", t)
-        yr, yi = np.asarray(yr), np.asarray(yi)
-        t = add("d2h", t)
-        out = block_of_segments(yr, yi)
-        add("write", t)
-        return out
+        with stage("read"):
+            re, im = segments_of_block(data, fft_len)
+        with stage("launch"):
+            # every same-shaped block hits the process-level plan cache:
+            # the jit'd callable is built once, the cufftPlanMany
+            # amortization
+            p = fft_api.plan(kind="c2c", n=fft_len,
+                             batch_shape=re.shape[:-1], impl=impl,
+                             verify=verify, tune=tune,
+                             wisdom_path=wisdom_path)
+            yr, yi = p.execute(jnp.asarray(re), jnp.asarray(im))
+        with stage("device_wait"):
+            jax.block_until_ready((yr, yi))  # the per-block sync
+        with stage("d2h"):
+            yr, yi = np.asarray(yr), np.asarray(yi)
+        with stage("write"):
+            return block_of_segments(yr, yi)
 
     return map_fn
 
@@ -122,20 +126,22 @@ def run_job(store: BlockStore, out_dir, *, fft_len: int, impl: str,
                                                        verify=verify))
         stats = job.run()
         return job, stats, dict(stats.stage_s)
-    stage_s = {k: 0.0 for k in ("read", "h2d", "compute", "d2h", "write")}
+    stage_s = dict.fromkeys(SERIAL_STAGES, 0.0)
     lock = threading.Lock()  # map tasks run on the job's worker pool
 
-    def add(stage: str, t0: float) -> float:
-        now = time.monotonic()
-        with lock:
-            stage_s[stage] += now - t0
-        return now
+    def stage(name: str):
+        return spans.timed(f"fft.serial.{name}", stage_s, name, lock)
 
     if verify != "off":
-        from dataclasses import replace as _replace
-        cfg = _replace(cfg, verify_fn=parseval_verify_fn(fft_len))
-    job = MapOnlyJob(_TimedStore(store, add), out_dir,
-                     serial_map_fn(fft_len, impl, add, verify,
+        check = parseval_verify_fn(fft_len)
+
+        def verify_fn(data: bytes, out: bytes, index: int) -> None:
+            with stage("verify"):
+                check(data, out, index)
+
+        cfg = replace(cfg, verify_fn=verify_fn)
+    job = MapOnlyJob(_TimedStore(store, stage), out_dir,
+                     serial_map_fn(fft_len, impl, stage, verify,
                                    tune=tune, wisdom_path=wisdom_path),
                      config=cfg)
     stats = job.run()
@@ -326,14 +332,12 @@ def main(argv=None):
     # NOTE: stage clocks are per-thread sums; in pipelined mode they run
     # concurrently, so these fractions are shares of total STAGE TIME
     # (thread-seconds of work), not a wall-clock split. The device side is
-    # compute + d2h: with async dispatch the launch call returns in
-    # microseconds and the real device wait surfaces at realization (the
-    # d2h clock), so counting "compute" alone would report ~0 fft work on
-    # accelerators. The Amdahl model below calibrates on wall time (t_job)
-    # and is unaffected.
-    fft_s = stage_s.get("compute", 0.0) + stage_s.get("d2h", 0.0)
+    # launch (H2D copy and enqueue) + device_wait (the wait for the
+    # transform); the rest is host work and I/O. The Amdahl model below
+    # calibrates on wall time (t_job) and is unaffected.
+    fft_s = stage_s.get("launch", 0.0) + stage_s.get("device_wait", 0.0)
     io_s = sum(v for k, v in stage_s.items()
-               if k not in ("compute", "d2h"))
+               if k not in ("launch", "device_wait"))
     p_frac = fit_parallel_fraction(io_s, fft_s)
     n = n_seg * args.fft_len
     unit = calibrate_unit_time(n, t_job, servers=1, cores=args.workers,

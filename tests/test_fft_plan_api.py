@@ -184,6 +184,27 @@ def test_zero_retrace_on_repeat_execute(rng):
     assert p2 is p and p.trace_counts["forward"] == 1
 
 
+def test_each_plan_names_its_program():
+    """A device trace tells plans apart by their XLA module: the full batch
+    from the tail, and the donated and inverse variants, all under the
+    ``jit_counted`` prefix the benchmark reads."""
+    full = fft_api.plan(kind="c2c", n=256, batch_shape=(8,), impl="ref")
+    tail = fft_api.plan(kind="c2c", n=256, batch_shape=(2,), impl="ref")
+    x8 = jnp.zeros((8, 256), jnp.float32)
+    x2 = jnp.zeros((2, 256), jnp.float32)
+
+    def module(jitted, x):
+        first = jitted.lower(x, x).as_text().splitlines()[0]
+        return first.split()[1].lstrip("@")
+
+    names = [module(full.executable, x8), module(tail.executable, x2),
+             module(full._forward_donated(), x8),
+             module(full._inverse()[1], x8)]
+    assert names == ["jit_counted_c2c_256_b8_ref", "jit_counted_c2c_256_b2_ref",
+                     "jit_counted_c2c_256_b8_ref_donated",
+                     "jit_counted_c2c_256_b8_ref_inverse"]
+
+
 def test_plan_is_frozen():
     p = fft_api.plan(kind="c2c", n=64, batch_shape=(1,))
     with pytest.raises(AttributeError, match="frozen"):

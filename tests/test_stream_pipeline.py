@@ -7,16 +7,19 @@ speculation / crash-restart semantics, and exactly two cached plans for a
 coalesced run (full batch + tail) with zero retraces.
 """
 
+import glob
 import json
 import threading
 import time
 
+import jax
 import numpy as np
 import pytest
 
 from repro.core.pipeline import (BlockStore, JobConfig, MapOnlyJob,
                                  SegmentFFTTransform, StagingPool)
 from repro.core.pipeline.maponly import Manifest, TaskState
+from repro.core.pipeline.stream import STAGES, WAITS
 from repro.core.pipeline.records import (block_of_segments,
                                          segment_block_bytes,
                                          segments_of_block)
@@ -79,6 +82,80 @@ def test_stream_bitwise_identical_with_tail(tmp_path):
     assert all(v >= 0 for v in stats.stage_s.values())
     # journal fd released after the run (incl. the late-finisher drain)
     assert job.manifest._fh is None
+
+
+def _traced_fft_spans(trace_dir, run):
+    """``run()`` under a profiler session; its result and the ``fft.*``
+    host spans of the trace, as (name, seconds, attributes)."""
+    from jax.profiler import ProfileData
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
+    try:
+        out = run()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(trace_dir / "**" / "*.xplane.pb"),
+                        recursive=True)
+    spans = [(e.name, e.duration_ns * 1e-9, dict(e.stats))
+             for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events
+             if e.name.startswith("fft.")]
+    return out, spans
+
+
+def test_stream_spans_are_the_stage_clocks(tmp_path):
+    """Every stream stage, wait and launch is a host span in a profiler
+    trace, and each stage clock is the sum of its spans' durations."""
+    store = _signal_store(tmp_path, blocks=6)
+    job = MapOnlyJob(store, tmp_path / "out",
+                     transform=SegmentFFTTransform(FFT_LEN, impl="ref"),
+                     config=JobConfig(coalesce=4, inflight=2,
+                                      speculation=False),
+                     pipelined=True)
+    stats, spans = _traced_fft_spans(tmp_path / "trace", job.run)
+    names = {n for n, _, _ in spans}
+    assert names >= {f"fft.stream.{s}" for s in STAGES} | {
+        "fft.stream.wait_decoded", "fft.stream.wait_inflight",
+        "fft.stream.dispatch", "fft.plan.launch"}
+    clocks = [(f"fft.stream.{s}", stats.stage_s[s]) for s in STAGES] + [
+        (f"fft.stream.wait_{w}", stats.wait_s[w]) for w in WAITS]
+    for name, clock in clocks:
+        total = sum(d for n, d, _ in spans if n == name)
+        assert abs(total - clock) <= 1e-3 + 0.01 * clock, name
+    # one 4-block batch and the 2-block tail, each through its own plan
+    dispatches = [a for n, _, a in spans if n == "fft.stream.dispatch"]
+    assert sorted(a["blocks"] for a in dispatches) == [2, 4]
+    assert all(a["queued"] >= 0 for a in dispatches)
+    assert {a["plan"] for n, _, a in spans if n == "fft.plan.launch"} == {
+        f"c2c_{FFT_LEN}_b{k * SEG_PER_BLOCK}_ref_donated" for k in (2, 4)}
+
+
+def test_dispatcher_waits_are_counted(tmp_path):
+    store = _signal_store(tmp_path, blocks=5)
+    stats = MapOnlyJob(store, tmp_path / "out", _serial_map_fn, JobConfig(),
+                       pipelined=True).run()
+    assert set(stats.wait_s) == set(WAITS)
+    assert all(v >= 0 for v in stats.wait_s.values())
+    assert not hasattr(stats, "task_seconds")
+
+
+def test_serial_launcher_stages_are_spans(tmp_path):
+    """The launcher's serial path times the stream's stages, less gather,
+    through the same spans (``fft.serial.<stage>``)."""
+    from repro.launch.fft_job import SERIAL_STAGES, run_job
+    store = _signal_store(tmp_path, blocks=3)
+    (job, stats, stage_s), spans = _traced_fft_spans(
+        tmp_path / "trace",
+        lambda: run_job(store, tmp_path / "out", fft_len=FFT_LEN,
+                        impl="ref", cfg=JobConfig(workers=2),
+                        pipelined=False, verify="parseval"))
+    assert stats.blocks_done == 3 and set(stage_s) == set(SERIAL_STAGES)
+    for stage in SERIAL_STAGES:
+        total = sum(d for n, d, _ in spans if n == f"fft.serial.{stage}")
+        assert total > 0, stage
+        assert abs(total - stage_s[stage]) <= 1e-3 + 0.01 * stage_s[stage]
 
 
 def test_stream_mapfn_path_identical(tmp_path):

@@ -10,6 +10,7 @@ storm/overhead gate is benchmarks/bench_verify.py (BENCH_verify.json).
 """
 
 import threading
+from contextlib import nullcontext
 
 import numpy as np
 import pytest
@@ -271,7 +272,7 @@ def test_maponly_serial_verify_fn_catches_post_map_corruption(tmp_path, rng):
         store.injector = injector
         job = MapOnlyJob(store, tmp_path / f"out{i}",
                          serial_map_fn(FFT_LEN, "ref",
-                                       lambda s, t0: t0), cfg)
+                                       lambda stage: nullcontext()), cfg)
         stats = job.run()
         job.merge(tmp_path / f"m{i}.bin")
         return stats, (tmp_path / f"m{i}.bin").read_bytes()
