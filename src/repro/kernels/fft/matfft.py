@@ -9,7 +9,7 @@ which only speaks GEMM. So the per-tile DFT is the Bailey four-step
       gathered in bit-reversed order into a (q, bt, 128) VMEM scratch
       -> q-point radix-2 DFT across the slabs (VPU, one vectorized
          butterfly per stage) -> twiddle
-      -> one (q*bt, 128) @ (128, 128) GEMM (MXU)
+      -> one (q*bt, 128) @ (128, 128) complex GEMM (MXU, three real dots)
       -> reorder: each slab, transposed, is a strided sublane store into a
          (n, bt) VMEM scratch; one transpose returns the natural order
 
@@ -32,7 +32,15 @@ Three kernel entry points share that tile math (DESIGN.md §3):
     the HBM bytes of the complex transform it replaces.
 
 The dots run at HIGHEST precision: a single bf16 MXU pass would lose about
-three digits.
+three digits. Every complex GEMM's right-hand side is a constant DFT
+matrix W = Wr + i*Wi, so it takes three real products (the Gauss form),
+not four:
+
+    k1 = (xr + xi) Wr,   k2 = xr (Wi - Wr),   k3 = xi (Wr + Wi)
+    re = k1 - k3,        im = k1 + k2
+
+The sums Wi - Wr and Wr + Wi are tables, formed in float64 on the host
+(plan.gauss_split); only xr + xi is added in the kernel.
 
 The optional ``global_twiddle`` fuses the four-step's outer twiddle
 multiply into the kernel's final store, computed in registers from the
@@ -111,18 +119,23 @@ def _cmul(ar, ai, br, bi):
     return ar * br - ai * bi, ar * bi + ai * br
 
 
-def _cgemm(ar, ai, br, bi):
-    """Planar complex GEMM with f32 accumulation (4 real MXU GEMMs)."""
-    return _dot(ar, br) - _dot(ai, bi), _dot(ar, bi) + _dot(ai, br)
+def _cgemm(ar, ai, wr, wd, ws):
+    """Planar complex GEMM (ar + i*ai) @ W with f32 accumulation, in three
+    real MXU GEMMs. W is constant, given as wr = Re W, wd = Im W - Re W
+    and ws = Re W + Im W (plan.gauss_split), so its sums cost nothing
+    here: re = (ar + ai) wr - ai ws, im = (ar + ai) wr + ar wd."""
+    k1 = _dot(ar + ai, wr)
+    return k1 - _dot(ai, ws), k1 + _dot(ar, wd)
 
 
 # ---------------------------------------------------------------------------
 # shared in-VMEM tile DFT (used by every kernel entry point)
 
 
-def _tile_dft_direct(xr, xi, wr, wi):
-    """Direct DFT of a (bt, n) VMEM tile: one complex GEMM."""
-    return _cgemm(xr, xi, wr, wi)
+def _tile_dft_direct(xr, xi, wr, wd, ws):
+    """Direct DFT of a (bt, n) VMEM tile: one complex GEMM with the DFT
+    matrix in the form of ``_cgemm``."""
+    return _cgemm(xr, xi, wr, wd, ws)
 
 
 def _slab_dft(xr, xi, sr, si):
@@ -145,15 +158,16 @@ def _slab_dft(xr, xi, sr, si):
     return xr, xi
 
 
-def _tile_dft_4step(load, sr, si, tr, ti, w2r, w2i, xsr, xsi, ysr, ysi, *,
-                    rows: int = 0):
+def _tile_dft_4step(load, sr, si, tr, ti, w2r, w2d, w2s, xsr, xsi, ysr,
+                    ysi, *, rows: int = 0):
     """In-VMEM four-step DFT of a (bt, q*128) tile.
 
     ``load(s)`` returns the (re, im) planes of lane slab ``s`` of the tile
     (im None for a real tile). Sample i = i1*128 + i2 and bin k = o2*q + o1.
     The q slabs are gathered, in bit-reversed order, into the (q, bt, 128)
     scratch ``xsr``/``xsi``; stage 1 is the q-point DFT across them, then
-    the twiddle T[o1, i2] and one (q*bt, 128) @ (128, 128) stage-2 GEMM.
+    the twiddle T[o1, i2] and one (q*bt, 128) @ (128, 128) stage-2 GEMM,
+    its matrix ``w2r``/``w2d``/``w2s`` in the form of ``_cgemm``.
     The result for o1 is bins o2*q + o1, stored transposed into rows o1,
     o1+q, ... of the (n, bt) scratch ``ysr``/``ysi``. ``rows`` < 128 keeps
     only bins o2 < rows (the rfft half spectrum; its tables put those bins
@@ -177,7 +191,8 @@ def _tile_dft_4step(load, sr, si, tr, ti, w2r, w2i, xsr, xsi, ysr, ysi, *,
     ar, ai = _slab_dft(xsr[...], xsi[...], sr, si)
     br, bi = _cmul(ar, ai, tr, ti)
     shape = br.shape
-    cr, ci = _cgemm(br.reshape(-1, lanes), bi.reshape(-1, lanes), w2r, w2i)
+    cr, ci = _cgemm(br.reshape(-1, lanes), bi.reshape(-1, lanes), w2r, w2d,
+                    w2s)
     xsr[...] = cr.reshape(shape)
     xsi[...] = ci.reshape(shape)
 
@@ -253,9 +268,8 @@ def _row_dft(xr_ref, xi_ref, rows, tables, scratch):
     """Natural-order DFT of the (bt, n) tile ``x_ref[rows, :]``: the direct
     GEMM, or the four-step through its scratch refs."""
     if not scratch:
-        wr, wi = tables
-        return _tile_dft_direct(xr_ref[rows, :], xi_ref[rows, :], wr[...],
-                                wi[...])
+        return _tile_dft_direct(xr_ref[rows, :], xi_ref[rows, :],
+                                *(t[...] for t in tables))
     _tile_dft_4step(lambda s: (xr_ref[rows, s], xi_ref[rows, s]),
                     *(t[...] for t in tables), *scratch)
     ysr, ysi = scratch[2:]
@@ -277,14 +291,14 @@ def _matfft_kernel(*refs, n_tables: int, twiddle_n: int, period: int):
 def _leaf_tables(n: int, table_spec):
     """Operands and BlockSpecs of the tile DFT of length n: the direct
     matrix, or the four-step's stage twiddles, (q, 1, 128) twiddle T and
-    128-point DFT matrix."""
+    128-point DFT matrix; each DFT matrix in the form of ``_cgemm``."""
     if n <= DIRECT_N:
-        tables = fft_plan.dft_matrix(n)
+        tables = fft_plan.gauss_dft_matrix(n)
     else:
         q, lanes = fft_plan.leaf_split(n)
         tr, ti = fft_plan.twiddle_table(q, lanes, n)
         tables = (*fft_plan.slab_twiddles(q), tr.reshape(q, 1, lanes),
-                  ti.reshape(q, 1, lanes), *fft_plan.dft_matrix(lanes))
+                  ti.reshape(q, 1, lanes), *fft_plan.gauss_dft_matrix(lanes))
     return ([jnp.asarray(t) for t in tables],
             [table_spec(t.shape) for t in tables])
 
@@ -399,8 +413,7 @@ def _col_kernel(*refs, n_tables: int, cols: int, chunk: int, out_major: str,
         if squeeze:
             xr = jnp.concatenate([xr, jnp.zeros_like(xr)], axis=0)
             xi = jnp.concatenate([xi, jnp.zeros_like(xi)], axis=0)
-        wr, wi = tables
-        yr, yi = _tile_dft_direct(xr, xi, wr[...], wi[...])
+        yr, yi = _tile_dft_direct(xr, xi, *(t[...] for t in tables))
         if squeeze:
             yr, yi = yr[:1], yi[:1]
         yr, yi = _epilogue(yr, yi, row_base, twiddle_n, period)
@@ -654,9 +667,9 @@ def _rfft_pallas(x: jnp.ndarray, batch_tile: int | None,
         scratch, name = [], f"{what}_direct_{n}"
     else:
         q = n // fft_plan.LANES
-        tr, ti, gr, gi = fft_plan.real_four_step_tables(n, packed)
+        tr, ti, *g = fft_plan.real_four_step_tables(n, packed)
         tables = (*fft_plan.slab_twiddles(q), tr.reshape(q, 1, -1),
-                  ti.reshape(q, 1, -1), gr, gi)
+                  ti.reshape(q, 1, -1), *g)
         scratch, name = _scratch(n, bt, out_rows=m), f"{what}_{n}"
     yr, yi = _pallas(
         functools.partial(_rfft_kernel, n_tables=len(tables),

@@ -59,12 +59,38 @@ def leaf_split(n: int) -> tuple[int, int]:
     return (n // LANES, LANES) if n >= LANES else (1, n)
 
 
+def _dft_angles(n: int) -> np.ndarray:
+    idx = np.arange(n, dtype=np.float64)
+    return -2.0 * math.pi * np.outer(idx, idx) / n
+
+
 @functools.lru_cache(maxsize=None)
 def dft_matrix(n: int) -> tuple[np.ndarray, np.ndarray]:
     """Planar (re, im) forward DFT matrix W[i, o] = exp(-2j*pi*i*o/n), f32."""
-    idx = np.arange(n, dtype=np.float64)
-    ang = -2.0 * math.pi * np.outer(idx, idx) / n
+    ang = _dft_angles(n)
     return np.cos(ang).astype(np.float32), np.sin(ang).astype(np.float32)
+
+
+def gauss_split(wr: np.ndarray, wi: np.ndarray
+                ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """The constant right-hand side W = wr + i*wi of a complex GEMM in the
+    three-product (Gauss) form: (wr, wi - wr, wr + wi), the sums taken in
+    float64 and each rounded once to f32. With them
+
+        re(X W) = (xr + xi) wr - xi (wr + wi)
+        im(X W) = (xr + xi) wr + xr (wi - wr)
+    """
+    wr = np.asarray(wr, np.float64)
+    wi = np.asarray(wi, np.float64)
+    return tuple(a.astype(np.float32) for a in (wr, wi - wr, wr + wi))
+
+
+@functools.lru_cache(maxsize=None)
+def gauss_dft_matrix(n: int) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """The (n, n) forward DFT matrix in the three-product form of
+    ``gauss_split``."""
+    ang = _dft_angles(n)
+    return gauss_split(np.cos(ang), np.sin(ang))
 
 
 @functools.lru_cache(maxsize=None)
@@ -128,8 +154,9 @@ def real_four_step_tables(n: int, packed: bool):
 
     Sample s = c*LANES + l (c < q = n/LANES) and bin k = o2*q + o1, so
     stage 1 is the q-point DFT across the lane slabs. Returned: the
-    twiddle T[o1, l] (q, LANES) and the stage-2 matrix G[l, o2]
-    (LANES, LANES), of which bins o2 < LANES/2 (k < n/2) are kept.
+    twiddle T[o1, l] (q, LANES), planar, and the stage-2 matrix G[l, o2]
+    (LANES, LANES) in the three-product form of ``gauss_split``, of which
+    bins o2 < LANES/2 (k < n/2) are kept.
 
     ``packed=False`` (one-sided bins): T = W_n^{o1*l}, G = W_LANES^{l*o2}.
     ``packed=True`` (DFT_{n/2} of the even/odd packing):
@@ -147,7 +174,7 @@ def real_four_step_tables(n: int, packed: bool):
         g = np.exp(-2j * math.pi * np.outer(ln, ln) / LANES)
     f32 = np.float32
     return (t.real.astype(f32), t.imag.astype(f32),
-            g.real.astype(f32), g.imag.astype(f32))
+            *gauss_split(g.real, g.imag))
 
 
 @functools.lru_cache(maxsize=None)
@@ -207,11 +234,12 @@ class FftPlan:
 
     @property
     def gemm_macs(self) -> float:
-        """Actual real MACs issued by the matmul formulation (per batch row)."""
+        """Actual real MACs issued by the matmul formulation (per batch row):
+        three real products per complex one (``gauss_split``)."""
         if self.levels == 1:
-            return 4.0 * self.n * (self.n1 + self.n2)
+            return 3.0 * self.n * (self.n1 + self.n2)
         f1, f2 = leaf_split(self.n1), leaf_split(self.n2)
-        return 4.0 * self.n * (f1[0] + f1[1] + f2[0] + f2[1])
+        return 3.0 * self.n * (f1[0] + f1[1] + f2[0] + f2[1])
 
 
 # ---------------------------------------------------------------------------

@@ -1,12 +1,13 @@
 """Per-kernel allclose sweeps + hypothesis property tests for the FFT stack."""
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.kernels.fft import ops, plan, ref
-from repro.kernels.fft.matfft import matfft
+from repro.kernels.fft.matfft import matfft, matfft_cols, rfft_leaf
 from repro.kernels.fft.stockham import stockham_fft
 
 
@@ -64,6 +65,78 @@ def test_epilogue_fusion_matches_unfused(rng, n):
     t = np.exp(-2j * np.pi * np.outer(g, np.arange(n)) / n_global)
     w = (np.asarray(yr) + 1j * np.asarray(yi)) * t
     assert _rel_err(np.asarray(fr), np.asarray(fi), w.real, w.imag) < 5e-6
+
+
+def _count_dots(jaxpr) -> int:
+    """dot_general equations in a jaxpr and every jaxpr nested in it."""
+    count = 0
+    for eqn in jaxpr.eqns:
+        count += eqn.primitive.name == "dot_general"
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    count += _count_dots(sub)
+    return count
+
+
+def _kernel_dots(fn, *args) -> int:
+    """dot_general equations inside the one pallas_call ``fn`` traces."""
+    calls = [e for e in jax.make_jaxpr(fn)(*args).jaxpr.eqns
+             if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    return _count_dots(calls[0].params["jaxpr"])
+
+
+_ROWS = jax.ShapeDtypeStruct((8, 1024), jnp.float32)
+_DIRECT = jax.ShapeDtypeStruct((8, 256), jnp.float32)
+_COLS = jax.ShapeDtypeStruct((1, 1024, 128), jnp.float32)
+_REAL = jax.ShapeDtypeStruct((8, 2048), jnp.float32)
+
+
+@pytest.mark.parametrize("fn, args", [
+    (lambda a, b: matfft(a, b, interpret=True), (_ROWS, _ROWS)),
+    (lambda a, b: matfft(a, b, interpret=True), (_DIRECT, _DIRECT)),
+    (lambda a, b: matfft_cols(a, b, interpret=True), (_COLS, _COLS)),
+    (lambda x: rfft_leaf(x, interpret=True), (_REAL,)),
+], ids=["matfft_1024", "dft_direct_256", "matfft_cols_1024", "rfft_2048"])
+def test_complex_gemm_takes_three_real_dots(fn, args):
+    """Each kernel runs one complex GEMM per tile, as three real dots."""
+    assert _kernel_dots(fn, *args) == 3
+
+
+# max_rel_l2 of the paper_capture_c2c1024 configuration
+_CAPTURE_LIMIT = 1e-6
+
+
+def _worst_row_rel_l2(got_r, got_i, x):
+    want = np.fft.fft(x.astype(np.complex128), axis=-1)
+    got = np.asarray(got_r, np.float64) + 1j * np.asarray(got_i, np.float64)
+    return float((np.linalg.norm(got - want, axis=-1)
+                  / np.linalg.norm(want, axis=-1)).max())
+
+
+@pytest.mark.parametrize("kind, n", [
+    ("rows", 256), ("rows", 1024), ("rows", 16384), ("cols", 1024)])
+def test_matfft_within_capture_limit(kind, n):
+    """Worst-row relative L2 error against float64 numpy stays within the
+    paper_capture_c2c1024 configuration's ``max_rel_l2``. Interpret mode
+    takes every dot in exact f32, so this guards the three-product form's
+    algebra and its f32 tables, not the MXU's precision on a chip."""
+    r = np.random.default_rng(n)
+    if kind == "rows":
+        x = (r.standard_normal((8, n))
+             + 1j * r.standard_normal((8, n))).astype(np.complex64)
+        yr, yi = matfft(jnp.asarray(x.real), jnp.asarray(x.imag),
+                        interpret=True)
+    else:
+        # (B, L, C) columns: logical row b*C + c is x[b, :, c]
+        x3 = (r.standard_normal((1, n, 128))
+              + 1j * r.standard_normal((1, n, 128))).astype(np.complex64)
+        yr, yi = matfft_cols(jnp.asarray(x3.real), jnp.asarray(x3.imag),
+                             interpret=True)
+        x = x3[0].T
+    assert _worst_row_rel_l2(yr, yi, x) <= _CAPTURE_LIMIT
 
 
 def test_dtype_is_float32(rng):
@@ -153,6 +226,16 @@ def test_dft_matrix_unitary():
     wr, wi = plan.dft_matrix(n)
     w = wr + 1j * wi
     assert np.abs(w @ w.conj().T / n - np.eye(n)).max() < 1e-5
+
+
+@pytest.mark.parametrize("n", [2, 128, 256])
+def test_gauss_dft_matrix_rebuilds_dft_matrix(n):
+    """(wr, wi - wr, wr + wi) recombine to the planar DFT matrix."""
+    wr, wd, ws = (a.astype(np.float64) for a in plan.gauss_dft_matrix(n))
+    dr, di = plan.dft_matrix(n)
+    assert np.array_equal(wr, dr)
+    assert np.abs((wd + ws) / 2 - di).max() < 1e-7
+    assert np.abs((ws - wd) / 2 - wr).max() < 1e-7
 
 
 def test_stockham_twiddle_packing():
