@@ -9,7 +9,8 @@
   bench/generators/<name>.py     a general generator, ``run(cell)``
   bench/metrics/<metric>.py      a per-layer metric's reader, ``read(ctx)``;
                                  a metric with none reads with its stem's
-  bench/staged/<cells>.json      entries of cells not yet in BENCHMARK.json
+  bench/staged/<cells>.json      entries of cells built and tested on the
+                                 CPU, not yet in BENCHMARK.json
 
 A later cell adds files and entries; it edits none of these.
 """
@@ -30,9 +31,11 @@ def load_benchmark(root: Path = ROOT) -> dict:
 
 
 def with_staged(bench: dict, bench_dir: Path = BENCH) -> dict:
-    """``bench`` with the entries of ``staged/*.json`` added: cells built
-    and tested on the CPU whose chip readings are not taken yet. A later
-    PR moves a staged file's entries into BENCHMARK.json."""
+    """``bench`` with the entries of ``staged/*.json`` added, where there
+    are any: cells built and tested on the CPU whose chip readings are
+    not taken yet, so that ``readings.py`` can take them. The entries of
+    a staged file move into BENCHMARK.json once the cell is proven on the
+    chip, and the file goes."""
     bench = dict(bench)
     for path in sorted((bench_dir / "staged").glob("*.json")):
         for key, entries in _json(path).items():

@@ -12,16 +12,21 @@ cache once the block is in memory, so each read is a read of the disk,
 as for a capture larger than memory.
 
 One ``MapOnlyJob(pipelined=True)`` then runs over the capture with the
-configuration's job settings. Set-up ends when its first output block is
-written: the job's program is loaded and its stages are full. The window
-opens there and closes at the first block that the same writer thread
-writes once ``seconds`` have passed (``Gate``), so it begins and ends on
-a written block and holds whole periods of the writers.
-The rate is the points of the blocks written inside the window over its
-length. Then the job is stopped: launches, reads and writes after the
-close raise ``WindowClosed``, which no retry takes up. The host spans
-``bench.read``/``gather``/``launch``/``realize``/``write`` mark the
-stages' calls, so a trace names what the host did in each idle gap.
+configuration's job settings. Set-up ends at the first block written
+once the read-ahead has filled (``Gate``): the stream's decoded queue at
+its cap (what the ``queued`` of ``fft.stream.dispatch`` reports) and a
+block in each reader's hand, where the device or the writers set the
+pace; or, where the reads set it, the dispatcher waiting on the decoded
+queue between two writes. The job is then as it is in its steady
+state. The window opens there and lasts ``seconds``. Its work is the
+points of the blocks written, each block counted by the share of its
+write (encode, write, fsync) that fell inside the window, so a block
+whose write straddles an edge counts in part and the rate does not
+step by whole blocks. Then the
+job is stopped: launches, reads and writes that start after the close
+raise ``WindowClosed``, which no retry takes up; writes under way
+finish. A trace names what the host did in each idle gap by the
+program's ``fft.stream.*`` spans.
 
 Checked: rows drawn from the seed of every block written in the window,
 read back from disk, against the configuration's float64 reference of
@@ -41,9 +46,14 @@ import numpy as np
 from bench import check, harness
 
 
-#: seconds past the window's end without a block written, or for the
-#: job to stop, before the run counts the job as failed
+#: seconds for the job to stop once the window has closed (twice that
+#: for the window to open) before the run counts the job as failed
 STALL_S = 60.0
+
+#: seconds the dispatcher waits on the decoded queue between two writes
+#: that show the reads set the pace: the read-ahead is then as full as
+#: it gets
+READS_PACE_S = 0.1
 
 
 class WindowClosed(BaseException):
@@ -52,43 +62,92 @@ class WindowClosed(BaseException):
     rather than retrying the block."""
 
 
+def readahead_blocks(job: dict) -> int:
+    """Blocks decoded and not yet gathered once the stream's read-ahead
+    is full: its decoded queue's bound in ``StreamExecutor``
+    (``core/pipeline/stream.py``), 2 x coalesce + readers, and a block
+    in each reader's hand, waiting to be put."""
+    coalesce, readers = max(job["coalesce"], 1), max(job["readers"], 1)
+    return (2 * coalesce + readers) + readers
+
+
 class Gate:
-    """The window's clock as the job's writers see it: which blocks were
-    written when and by which writer thread, and whether the window has
+    """What the job did when, as the benchmark sees it: blocks decoded,
+    gathered, read and written (each write's start and end, and its
+    writer thread); whether the window has opened and whether it has
     closed.
 
-    The window closes at the first block written once ``deadline`` has
-    passed by the writer that wrote the block opening it. Writers finish
-    blocks at one rate but at different phases, so a window that began
-    with one writer's block and ended with another's would hold half a
-    block's time more or less than its blocks; ending on the same
-    writer's block holds whole periods of both."""
+    The read-ahead's depth is the blocks decoded that are neither in a
+    group gathered so far nor written. It is full once the depth has
+    reached ``full`` (``by`` "cap"), or once the dispatcher has waited
+    ``READS_PACE_S`` or more on the decoded queue between two writes
+    (``by`` "reads"; ``waits`` is the job's ``JobStats.wait_s``). The
+    window opens at the first block written once it is full."""
 
-    def __init__(self):
+    def __init__(self, full: int):
         self.lock = threading.Lock()
-        self.written = []     # (monotonic time, block index, writer thread)
+        self.full = full
+        self.waits = {}       # the dispatcher's waits, JobStats.wait_s
+        self.waited = None    # its wait on decoded blocks at the last write
+        self.written = []     # (start, end, block index, writer thread)
+        self.encoding = {}    # writer thread -> when its encode began
         self.reads = []                   # (monotonic time, block index)
-        self.wrote_one = threading.Event()  # set at every block written
+        self.decoded = set()  # blocks decoded and not yet gathered
+        self.done = set()     # blocks written
+        self.depths = []      # (monotonic time, depth) at every decode
+        self.started = None   # the job's start
+        self.filled = None    # when the read-ahead was first full
+        self.by = None        # what showed it full: "cap" or "reads"
+        self.opened = threading.Event()   # the window has opened
         self.closed = threading.Event()   # the window has closed
-        self.deadline = None
-        self.anchor = None    # the writer thread whose block opened it
 
     def check_open(self) -> None:
         if self.closed.is_set():
             raise WindowClosed
 
-    def wrote(self, index: int) -> None:
+    def decode(self, index: int) -> None:
+        now = time.monotonic()
+        with self.lock:
+            self.decoded.add(index)
+            depth = len(self.decoded - self.done)
+            self.depths.append((now, depth))
+            if self.filled is None and depth >= self.full:
+                self.filled, self.by = now, "cap"
+
+    def gather(self, indices) -> None:
+        with self.lock:
+            self.decoded.difference_update(indices)
+
+    def encode(self) -> None:
+        with self.lock:
+            self.encoding[threading.get_ident()] = time.monotonic()
+
+    def wrote(self, index: int, start: float) -> None:
+        """A block written by this thread: its write began at its encode,
+        where the thread encoded it, else at ``start``."""
         now, writer = time.monotonic(), threading.get_ident()
         with self.lock:
-            self.written.append((now, index, writer))
-            self.wrote_one.set()
-            if (self.deadline is not None and now >= self.deadline
-                    and writer == self.anchor):
-                self.closed.set()
+            start = self.encoding.pop(writer, start)
+            self.written.append((start, now, index, writer))
+            self.done.add(index)
+            waited = self.waits.get("decoded", 0.0)
+            if self.filled is None and self.waited is not None and (
+                    waited - self.waited >= READS_PACE_S):
+                self.filled, self.by = now, "reads"
+            self.waited = waited
+            if self.filled is not None:
+                self.opened.set()
 
     def read(self, index: int) -> None:
         with self.lock:
             self.reads.append((time.monotonic(), index))
+
+
+def window_share(start: float, end: float, lo: float, hi: float) -> float:
+    """The share of the interval ``[start, end]`` inside ``[lo, hi]``."""
+    inside = min(end, hi) - max(start, lo)
+    return max(inside, 0.0) / (end - start) if end > start else float(
+        lo <= start <= hi)
 
 
 def _store_class():
@@ -115,25 +174,25 @@ def _store_class():
         def read_block(self, index: int, verify: bool = True) -> bytes:
             self.gate.check_open()
             part = self.parts[index % len(self.parts)]
-            with _span("bench.read"):
-                try:
-                    data = part.read_block(0, verify)
-                finally:
-                    self.evict(part)
+            try:
+                data = part.read_block(0, verify)
+            finally:
+                self.evict(part)
             self.gate.read(index)
             return data
 
         def write_output_block(self, out_dir, index: int, data) -> None:
             self.gate.check_open()
-            with _span("bench.write"):
-                super().write_output_block(out_dir, index, data)
-            self.gate.wrote(index)
+            start = time.monotonic()
+            super().write_output_block(out_dir, index, data)
+            self.gate.wrote(index, start)
 
     return CaptureStore, BlockStore
 
 
 class _GatedTransform:
-    """The job's transform, launching nothing once the window has closed."""
+    """The job's transform, telling the gate what was decoded, gathered
+    and encoded, and launching nothing once the window has closed."""
 
     def __init__(self, inner, gate: Gate):
         self._inner = inner
@@ -142,23 +201,22 @@ class _GatedTransform:
     def __getattr__(self, name):
         return getattr(self._inner, name)
 
+    def decode(self, data, index):
+        d = self._inner.decode(data, index)
+        self._gate.decode(index)
+        return d
+
     def gather(self, group):
-        with _span("bench.gather"):
-            return self._inner.gather(group)
+        self._gate.gather([d.index for d in group])
+        return self._inner.gather(group)
 
     def launch(self, batch):
         self._gate.check_open()
-        with _span("bench.launch"):
-            return self._inner.launch(batch)
+        return self._inner.launch(batch)
 
-    def realize(self, handle):
-        with _span("bench.realize"):
-            return self._inner.realize(handle)
-
-
-def _span(name: str):
-    import jax
-    return jax.profiler.TraceAnnotation(name)
+    def encode(self, host, row0, d):
+        self._gate.encode()
+        return self._inner.encode(host, row0, d)
 
 
 def _capture(cell: harness.Cell) -> np.ndarray:
@@ -217,7 +275,7 @@ def run(cell: harness.Cell, counter: harness.CompileCounter,
     capture = _capture(cell)
     t_made = time.monotonic()
     distinct = int(cfg["distinct_blocks"])
-    gate = Gate()
+    gate = Gate(readahead_blocks(jc))
     capture_store, block_store = _store_class()
     parts = [block_store(cell.tmp / f"capture{b}", block_bytes=block_bytes,
                          replication=jc["replication"])
@@ -242,6 +300,7 @@ def run(cell: harness.Cell, counter: harness.CompileCounter,
         pipelined=True,
         transform=_GatedTransform(
             transform or SegmentFFTTransform(n, impl=cfg["impl"]), gate))
+    gate.waits = job.stats.wait_s
     ended = {}
 
     def job_thread():
@@ -250,41 +309,44 @@ def run(cell: harness.Cell, counter: harness.CompileCounter,
         except RuntimeError as e:
             ended["error"] = e
         finally:
-            gate.wrote_one.set()
+            gate.opened.set()
             gate.closed.set()
 
     harness.steady()
     worker = threading.Thread(target=job_thread, name="bench.job",
                               daemon=True)
+    gate.started = time.monotonic()
     worker.start()
-    gate.wrote_one.wait()
+    # the read-ahead fills while the first run of a checkout compiles
+    opened = gate.opened.wait(2 * STALL_S)
 
     with harness.Window(cell.trace, cell.tmp, counter) as win:
         with gate.lock:
-            gate.anchor = gate.written[0][2]
-            gate.deadline = win.start + cell.seconds
             stage0 = dict(job.stats.stage_s)
             retries0 = job.stats.retries
-        with _span("bench.stream"):
-            stalled = not gate.closed.wait(cell.seconds + STALL_S)
-            gate.closed.set()
+        # the job ends before the close only if it failed or ran out
+        ended_early = not opened or gate.closed.wait(cell.seconds)
         with gate.lock:
             stage1 = dict(job.stats.stage_s)
             retries1 = job.stats.retries
+    gate.closed.set()
     peak = harness.memory_peak(jax.local_devices()[:cell.chips])
     worker.join(STALL_S)
 
     err = ended.get("error")
     stopped = err is not None and isinstance(err.__cause__, WindowClosed)
-    job_failed = stalled or worker.is_alive() or (err is not None
-                                                  and not stopped)
-    inside = [(t, i, w) for t, i, w in gate.written
-              if win.start < t <= win.end]
-    done = [i for _, i, _ in inside]
+    job_failed = ended_early or worker.is_alive() or (err is not None
+                                                      and not stopped)
+    first = {}            # each block's first write: a twin's is a repeat
+    for s, e, i, w in gate.written:
+        first.setdefault(i, (s, e, w))
+    share = {i: window_share(s, e, win.start, win.end)
+             for i, (s, e, _) in first.items()}
+    unique = sorted(i for i, f in share.items() if f > 0)
+    done = [i for _, e, i, _ in gate.written if win.start < e <= win.end]
     writers = {w: n for n, w in enumerate(dict.fromkeys(
-        w for _, _, w in gate.written))}
+        w for *_, w in gate.written))}
     reads = [i for t, i in gate.reads if win.start < t <= win.end]
-    unique = sorted(set(done))
 
     rng = harness.numpy_rng(cell.seed, 2)
     out_dir = cell.tmp / "out"
@@ -301,7 +363,7 @@ def run(cell: harness.Cell, counter: harness.CompileCounter,
     return harness.Outcome(
         window_start=win.start,
         metrics={"mpoints_per_s.file":
-                 len(unique) * points_per_block / win.seconds / 1e6},
+                 sum(share.values()) * points_per_block / win.seconds / 1e6},
         attempted=len(unique), failed=(retries1 - retries0) + job_failed,
         checks=[("max_rel_l2", err_l2, cfg["check"]["max_rel_l2"])],
         compiles_in_window=win.compiles, memory_peak_bytes=peak,
@@ -309,11 +371,22 @@ def run(cell: harness.Cell, counter: harness.CompileCounter,
         layer={"stage_s": _stage_delta(stage1, stage0),
                "bytes_read": len(reads) * block_bytes,
                "bytes_written": len(done) * block_bytes,
-               "blocks_written": len(unique), "window_s": win.seconds,
-               "writes": [[t - win.start, writers[w]] for t, _, w in inside],
+               "blocks_in_window": sum(share.values()),
+               "window_s": win.seconds,
+               "writes": [[s - win.start, e - win.start, writers[w]]
+                          for i, (s, e, w) in sorted(first.items())
+                          if share[i] > 0],
                "ran_out": "stats" in ended,
                "setup_phases_s": {"capture": t_made - t0,
                                   "store": t_stored - t_made,
-                                  "first_block": win.start - t_stored},
+                                  "steady": win.start - t_stored},
+               "readahead": {
+                   "full": gate.full,
+                   "by": gate.by,
+                   "filled_s": (None if gate.filled is None
+                                else gate.filled - gate.started),
+                   "depths": [[round(t - gate.started, 3), d]
+                              for t, d in gate.depths if t <= win.start]},
+               "blocks_written_run": len(gate.written),
                "speculative": stats.speculative_launches,
                "batches": stats.batches})

@@ -7,7 +7,7 @@ from bench import programspans
 
 
 def read(ctx):
-    spans = programspans.of(ctx)
+    spans = ctx.program_spans
     if spans is None:
         return None
     return programspans.median_us(spans, "fft.plan.launch",

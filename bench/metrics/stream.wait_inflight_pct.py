@@ -8,7 +8,7 @@ from bench import programspans
 
 
 def read(ctx):
-    spans = programspans.of(ctx)
+    spans = ctx.program_spans
     if spans is None:
         return None
     return programspans.union_pct(spans, "fft.stream.wait_inflight",

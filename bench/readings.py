@@ -11,7 +11,8 @@ Prints one JSON line per seed and kind: ``{"kind", "seed", "value",
 ``check.verdict`` a run's checks go through, at the configuration's
 limit, and has to be false.
 Not run by the benchmark itself; its numbers and the limits set from
-them are in PERF.md. Staged cells (``bench/staged/``) are found too.
+them are in PERF.md. A cell staged in ``bench/staged/``, where there is
+one, is found too (``discover.with_staged``).
 """
 
 from __future__ import annotations

@@ -1,13 +1,11 @@
-"""CPU runs of the NPB FT generator on four virtual devices at a small grid:
-a sound run is correct; the control and faults planted under the timed
-path (the pencil) are not. The runs go to a child process, which sets
-the CPU device count before JAX starts.
+"""CPU runs of the NPB FT cell of ``BENCHMARK.json`` on four virtual
+devices at a small grid: a sound run is correct; the control and faults
+planted under the timed path (the pencil) are not. The runs go to a
+child process, which sets the CPU device count before JAX starts.
 
-The cell is not in ``BENCHMARK.json`` yet: its entries wait in
-``bench/staged/npb_ft_classD.json`` (``discover.with_staged``). The
-small grid is held to ``LIMIT``, this test's own limit: a sound run
-reads about 3e-7 there and the bf16 x3 control above 5e-6; the
-configuration's limit for class D is to be set on the chip.
+The small grid is held to the configuration's own limit, ``LIMIT``, set
+for class D from chip readings (PERF.md): a sound run reads about 3e-7
+there and the bf16 x3 control above it.
 
 The grid keeps class D's proportion of NPB's checksum points to x: 32
 points over an x of 64, so, as at class D, they all but miss the chips
@@ -22,20 +20,20 @@ import sys
 import numpy as np
 import pytest
 
-from bench import discover
+from bench import check, discover
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-LIMIT = 1.5e-6
+LIMIT = discover.load_config("npb_ft_classD")["check"]["max_rel_l2"]
 
 CHILD = r"""
-import json, sys
+import json, sys, tempfile
+from pathlib import Path
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from bench import discover, run
+from bench import discover, harness, run
 
 cfg = discover.load_config("npb_ft_classD")
-cfg.update(shape=[16, 32, 64], alpha=1e-3, checksum_points=32,
-           check={"max_rel_l2": LIMIT})
+cfg.update(shape=[16, 32, 64], alpha=1e-3, checksum_points=32)
 traffic = dict(discover.load_traffic("iter"), sample_points=32)
 mesh = jax.sharding.Mesh(
     __import__("numpy").array(jax.devices()[:4]).reshape(2, 2),
@@ -77,7 +75,7 @@ def one_chip(vr, vi):
 faults = {"none": None, "unchanged": lambda vr, vi: (vr, vi),
           "no_exchange": local_only, "altered": altered,
           "one_chip": one_chip}
-bench = discover.with_staged(discover.load_benchmark())
+bench = discover.load_benchmark()
 runs = [(name, traffic) for name in faults]
 runs.append(("one_chip_npb_only", dict(traffic, points_per_chip=0)))
 for name, tr in runs:
@@ -86,6 +84,15 @@ for name, tr in runs:
     line, _ = run.run_cell(bench, "npb_ft_classD.iter", 2**35 + 3, 0.3,
                            False, config=cfg, traffic=tr, **kw)
     print(json.dumps({"fault": name, "line": line}), flush=True)
+with tempfile.TemporaryDirectory() as tmp:
+    cell = harness.Cell(name="npb_ft_classD.iter", config=cfg,
+                        traffic=traffic, seed=2**35 + 3, seconds=0.0,
+                        trace=False, chips=4,
+                        reference=discover.load_reference("npb_ft_classD"),
+                        tmp=Path(tmp))
+    checks = discover.load_generator("npb_ft").control(cell)
+print(json.dumps({"fault": "control", "line": {"checks": checks}}),
+      flush=True)
 """
 
 
@@ -94,8 +101,7 @@ def runs():
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4",
                PYTHONPATH=os.pathsep.join([ROOT, os.path.join(ROOT, "src")]))
-    child = f"LIMIT = {LIMIT!r}\n" + CHILD
-    out = subprocess.run([sys.executable, "-c", child], env=env, cwd=ROOT,
+    out = subprocess.run([sys.executable, "-c", CHILD], env=env, cwd=ROOT,
                          capture_output=True, text=True, timeout=600)
     assert out.returncode == 0, out.stderr[-4000:]
     rows = [json.loads(s) for s in out.stdout.splitlines()
@@ -109,6 +115,17 @@ def test_sound_run_is_correct(runs):
     assert set(line["metrics"]) == {"setup_s", "ft_s_per_iter"}
     assert line["device"]["count"] == 4
     assert line["checks"]["max_rel_l2"]["value"] < LIMIT
+
+
+def test_control_fails_the_limit_and_the_program_passes(runs):
+    """At the test grid the generator's control (the reference in bf16 x3
+    products in the program's place) fails the configuration's limit,
+    and the program, on the same seed, passes it."""
+    (name, value, limit), = runs["control"]["checks"]
+    assert name == "max_rel_l2" and limit == LIMIT
+    assert check.verdict([(name, value, limit)]) is False
+    program = runs["none"]["checks"]["max_rel_l2"]
+    assert program["limit"] == LIMIT and program["value"] < LIMIT < value
 
 
 @pytest.mark.parametrize("fault", ["unchanged", "no_exchange", "altered",

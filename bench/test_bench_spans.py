@@ -1,6 +1,7 @@
 """CPU tests of the readers of the program's own spans
-(``bench/programspans.py``): the numbers they reduce a window to, how
-they find the run's trace file, and None where there is nothing to read."""
+(``bench/programspans.py``): the numbers they reduce a window to, the
+spans of a real profiler trace reaching them through the run's context,
+and None where there is nothing to read."""
 
 import time
 
@@ -42,21 +43,21 @@ def test_median_and_union_of_program_spans():
                                   (30.0, 40.0)) is None
 
 
-def _context(window):
+def _context(window, spans=()):
     trace = xplane.Trace(devices={0: []},
-                         spans=[_span(WINDOW_SPAN, *window)], window=window)
+                         spans=[_span(WINDOW_SPAN, *window), *spans],
+                         window=window)
     return xplane.Context(trace=trace, layer={}, device_kind="TPU v5 lite")
 
 
-def _record(tmp_path, monkeypatch, program_spans: bool):
-    """A profiler trace where ``bench/run.py`` leaves it, with a window
-    that holds three launches and both dispatcher waits; the window read
-    from the file as ``xplane.load`` reads it."""
-    monkeypatch.setattr("tempfile.tempdir", str(tmp_path))
+def _record(tmp_path, program_spans: bool):
+    """A profiler trace of a window that holds three launches and both
+    dispatcher waits, read back as ``xplane.load`` reads its host spans;
+    the context of that window."""
+    from jax.profiler import ProfileData
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
-    jax.profiler.start_trace(str(tmp_path / "bench_x" / "trace"),
-                             profiler_options=opts)
+    jax.profiler.start_trace(str(tmp_path / "trace"), profiler_options=opts)
     try:
         with jax.profiler.TraceAnnotation(WINDOW_SPAN):
             if program_spans:
@@ -69,38 +70,58 @@ def _record(tmp_path, monkeypatch, program_spans: bool):
                 with jax.profiler.TraceAnnotation(
                         "fft.stream.wait_inflight"):
                     time.sleep(0.01)
-            time.sleep(0.02)
+            with jax.profiler.TraceAnnotation("other.span"):
+                time.sleep(0.001)
     finally:
         jax.profiler.stop_trace()
-    ctx = _context((0.0, 1.0))
-    (path,) = (tmp_path / "bench_x" / "trace").glob("**/*.xplane.pb")
-    (window,) = [s for s in programspans.host_spans(
-        str(path), path.stat().st_mtime_ns) if s.name == WINDOW_SPAN]
-    return _context((window.start, window.end)), ctx
+    (path,) = (tmp_path / "trace").glob("**/*.xplane.pb")
+    spans = xplane.host_spans(ProfileData.from_file(str(path)))
+    (window,) = [s for s in spans if s.name == WINDOW_SPAN]
+    return _context((window.start, window.end),
+                    [s for s in spans if s is not window])
 
 
-def test_readers_find_the_runs_trace(tmp_path, monkeypatch):
-    ctx, other = _record(tmp_path, monkeypatch, program_spans=True)
+def test_readers_find_the_runs_trace(tmp_path):
+    """The program's spans of a real trace reach the readers through the
+    context, and the readers reduce them to what the spans themselves
+    read: the median launch and each wait's share of the window, however
+    long the host took to run them."""
+    ctx = _record(tmp_path, program_spans=True)
+    spans = ctx.program_spans
+    assert sorted({s.name for s in spans}) == [
+        "fft.plan.launch", "fft.stream.wait_decoded",
+        "fft.stream.wait_inflight"]
     launch_us, decoded, inflight = (discover.load_reader(m)(ctx)
                                     for m in READERS)
-    assert 2000 <= launch_us < 2000 + 5000
-    assert 0 < inflight < decoded < 100
-    share = 100.0 * 0.02 / ctx.window_s
-    assert decoded == pytest.approx(share, rel=0.2)
-    # the newest trace file is another window's: nothing is read from it
-    assert all(discover.load_reader(m)(other) is None for m in READERS)
+
+    def lengths(name):
+        return [s.end - s.start for s in spans if s.name == name]
+
+    assert len(lengths("fft.plan.launch")) == 3
+    assert launch_us == pytest.approx(
+        1e6 * sorted(lengths("fft.plan.launch"))[1])
+    for share, name in ((decoded, "fft.stream.wait_decoded"),
+                        (inflight, "fft.stream.wait_inflight")):
+        (length,) = lengths(name)
+        assert 0 < share == pytest.approx(100.0 * length / ctx.window_s)
+    # the same spans seen from another window: nothing starts inside it
+    other = _context((ctx.trace.window[1] + 1.0, ctx.trace.window[1] + 2.0),
+                     spans)
+    assert discover.load_reader(READERS[0])(other) is None
+    assert discover.load_reader(READERS[1])(other) == 0.0
 
 
-def test_readers_read_nothing_where_the_program_wrote_no_span(
-        tmp_path, monkeypatch):
-    ctx, _ = _record(tmp_path, monkeypatch, program_spans=False)
+def test_readers_read_nothing_where_the_program_wrote_no_span(tmp_path):
+    ctx = _record(tmp_path, program_spans=False)
+    assert ctx.program_spans == []
     assert all(discover.load_reader(m)(ctx) is None for m in READERS)
 
 
-def test_readers_read_nothing_untraced(tmp_path, monkeypatch):
-    monkeypatch.setattr("tempfile.tempdir", str(tmp_path))
+def test_readers_read_nothing_untraced():
     ctx = xplane.Context(trace=None, layer={}, device_kind="TPU v5 lite")
+    assert ctx.program_spans is None
     assert all(discover.load_reader(m)(ctx) is None for m in READERS)
-    # traced, but no trace file where the run keeps it
-    assert all(discover.load_reader(m)(_context((0.0, 1.0))) is None
-               for m in READERS)
+    # traced, but only the benchmark's own spans
+    assert all(discover.load_reader(m)(_context(
+        (0.0, 1.0), [_span("bench.wait", 0.1, 0.2)])) is None
+        for m in READERS)

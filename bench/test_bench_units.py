@@ -82,6 +82,48 @@ def test_gap_attribution_names_the_host_span():
     assert "realize" in names and "bench.window" in names
 
 
+def test_gaps_are_named_by_the_innermost_span_of_any_thread():
+    """Benchmark and program spans of several threads overlap; each idle
+    piece takes the name of the shortest span that covers it, as a scan
+    of every span at every piece finds it."""
+    import random
+    rnd = random.Random(3)
+    ops = []
+    t = 0.0
+    for i in range(200):
+        t += rnd.uniform(0.001, 0.05)
+        ops.append(_ev(f"fusion.{i}", t, t + rnd.uniform(0.001, 0.05)))
+    spans = [xplane.Span("bench.window", 0.0, 10.0)]
+    for i in range(300):
+        s = rnd.uniform(0.0, 10.0)
+        name = rnd.choice(["fft.stream.read", "fft.stream.write",
+                           "fft.stream.wait_inflight", "bench.wait"])
+        spans.append(xplane.Span(name, s, s + rnd.uniform(0.0005, 2.0)))
+    tr = xplane.Trace(devices={0: ops}, spans=spans, window=(0.0, 10.0))
+
+    def brute():
+        pieces = []
+        for a, b in intervals.gaps(tr._clip(ops), 0.0, 10.0):
+            cuts = sorted({a, b, *(x for s in spans for x in (s.start, s.end)
+                                   if a < x < b)})
+            for lo, hi in zip(cuts, cuts[1:]):
+                mid = (lo + hi) / 2
+                inside = [s for s in spans if s.start <= mid < s.end]
+                name = min(inside, key=lambda s: s.end - s.start).name
+                if pieces and pieces[-1][0] == name and pieces[-1][2] == lo:
+                    pieces[-1][1] += hi - lo
+                    pieces[-1][2] = hi
+                else:
+                    pieces.append([name, hi - lo, hi])
+        pieces.sort(key=lambda p: -p[1])
+        return [[n, s] for n, s, _ in pieces[:10]]
+
+    got = tr.idle_gaps(top=10)
+    assert got == brute()
+    assert {n for n, _ in got} & {"fft.stream.read", "fft.stream.write",
+                                  "fft.stream.wait_inflight"}
+
+
 def test_device_op_breakdown_is_sorted_by_time():
     ops = _trace().device_ops(top=10)
     assert ops[0] == ["jit_step:fusion.1", pytest.approx(2.0)]
@@ -143,11 +185,15 @@ def test_a_reader_is_found_by_its_stem(tmp_path):
 
 def test_config_files_match_benchmark_entries():
     bench = discover.load_benchmark()
+    assert {"paper_capture_c2c1024", "npb_ft_classD"} <= {
+        c["name"] for c in bench["configs"]}
     for c in bench["configs"]:
         cfg = discover.load_config(c["name"])
         assert c["file"] == f"bench/configs/{c['name']}.json"
         assert cfg["reduced"] == c["reduced"]
-        assert math.isfinite(min(cfg["check"].values()))
+        assert all(lim is not None and math.isfinite(lim) and lim > 0
+                   for lim in cfg["check"].values())
+
 
 
 @pytest.mark.parametrize("value,limit,ok", [

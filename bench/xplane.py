@@ -2,20 +2,23 @@
 
 ``load`` reads the ``.xplane.pb`` that ``jax.profiler`` wrote: the ops
 each TPU ran (the "XLA Ops" line of each ``/device:TPU:<n>`` plane, with
-the XLA module, i.e. the jitted program, each belongs to) and the
-benchmark's own host spans (names starting with ``bench.``). A
-``Trace`` reduces them, always within the window span:
+the XLA module, i.e. the jitted program, each belongs to) and the host
+spans of the benchmark (names starting with ``bench.``) and of the
+program (``fft.``, ``repro.spans``). A ``Trace`` reduces them, always
+within the window span:
 
   busy       the union of the intervals in which any op ran on a device;
   idle       one minus busy over the window, averaged over the devices;
-  gaps       the idle intervals, each split by the innermost benchmark
-             span that the host was in;
+  gaps       the idle intervals, each split by the innermost host span,
+             the benchmark's or the program's, that the host was in;
   exposed    the time in which a collective ran on a device and no other
              op of the same program did, over that program's busy time.
 """
 
 from __future__ import annotations
 
+import bisect
+import heapq
 import re
 from collections import defaultdict
 from dataclasses import dataclass, field
@@ -25,6 +28,8 @@ from bench.harness import WINDOW_SPAN
 
 #: host spans the benchmark writes; the window is ``bench.window``
 SPAN_PREFIX = "bench."
+#: host spans the program writes (``repro.spans``)
+PROGRAM_PREFIX = "fft."
 #: HLO opcodes that move data between chips (``-start``/``-done`` halves
 #: of the asynchronous ones count as the opcode)
 COLLECTIVE_OPCODES = frozenset({
@@ -78,7 +83,7 @@ class Span:
 @dataclass
 class Trace:
     devices: dict            # device id -> [Op]
-    spans: list              # [Span], the benchmark's host spans
+    spans: list              # [Span], the benchmark's and program's
     window: tuple            # (start, end) seconds
     runs: dict = field(default_factory=dict)  # device -> [(s, e, module)]
 
@@ -135,23 +140,28 @@ class Trace:
             shares.append(100.0 * intervals.length(alone) / busy)
         return sum(shares) / len(shares) if shares else None
 
-    def _innermost(self, t: float) -> str:
-        inside = [s for s in self.spans if s.start <= t < s.end]
-        if not inside:
-            return "no benchmark span"
-        return min(inside, key=lambda s: s.end - s.start).name
-
     def idle_gaps(self, top: int = 10, dev=None) -> list:
         """The longest idle pieces of one device (the first by default),
-        each named by the innermost host span it fell in."""
+        each named by the innermost host span it fell in: the shortest
+        span, of any thread, that covers it."""
         dev = min(self.devices) if dev is None else dev
-        pieces = []
+        spans = sorted(self.spans, key=lambda s: s.start)
+        bounds = sorted({t for s in spans for t in (s.start, s.end)})
+        # one sweep in time: the spans begun so far, shortest on top; a
+        # span that has ended leaves once it reaches the top
+        heap, k, pieces = [], 0, []
         for a, b in intervals.gaps(self._clip(self.devices[dev]),
                                    *self.window):
-            cuts = sorted({a, b, *(t for s in self.spans
-                                   for t in (s.start, s.end) if a < t < b)})
+            cuts = [a, *bounds[bisect.bisect_right(bounds, a):
+                               bisect.bisect_left(bounds, b)], b]
             for lo, hi in zip(cuts, cuts[1:]):
-                name = self._innermost((lo + hi) / 2)
+                while k < len(spans) and spans[k].start <= lo:
+                    s = spans[k]
+                    heapq.heappush(heap, (s.end - s.start, k, s))
+                    k += 1
+                while heap and heap[0][2].end <= lo:
+                    heapq.heappop(heap)
+                name = heap[0][2].name if heap else "no host span"
                 if pieces and pieces[-1][0] == name and pieces[-1][2] == lo:
                     pieces[-1][1] += hi - lo
                     pieces[-1][2] = hi
@@ -182,6 +192,15 @@ class Context:
     trace: Trace | None
     layer: dict
     device_kind: str
+
+    @property
+    def program_spans(self) -> list | None:
+        """The program's host spans in the traced run (``fft.*``); None
+        when the run was not traced."""
+        if self.trace is None:
+            return None
+        return [s for s in self.trace.spans
+                if s.name.startswith(PROGRAM_PREFIX)]
 
     @property
     def busy_s(self) -> float:
@@ -232,21 +251,26 @@ def _device_ops(plane) -> list:
     return ops, [(s * 1e-9, e * 1e-9, m) for s, e, m in runs]
 
 
+def host_spans(pd) -> list:
+    """The benchmark's and the program's host spans of a read trace
+    (``jax.profiler.ProfileData``)."""
+    return [Span(e.name, e.start_ns * 1e-9,
+                 (e.start_ns + e.duration_ns) * 1e-9)
+            for plane in pd.planes if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events
+            if e.name.startswith((SPAN_PREFIX, PROGRAM_PREFIX))]
+
+
 def load(path: str) -> Trace:
-    """Read a ``.xplane.pb``: TPU ops, module runs and benchmark spans."""
+    """Read a ``.xplane.pb``: TPU ops, module runs and host spans."""
     from jax.profiler import ProfileData
     pd = ProfileData.from_file(path)
-    devices, runs, spans = {}, {}, []
+    devices, runs = {}, {}
     for plane in pd.planes:
         if plane.name.startswith("/device:TPU:"):
             dev = int(plane.name.rsplit(":", 1)[1])
             devices[dev], runs[dev] = _device_ops(plane)
-        elif plane.name.startswith("/host:"):
-            for line in plane.lines:
-                spans += [Span(e.name, e.start_ns * 1e-9,
-                               (e.start_ns + e.duration_ns) * 1e-9)
-                          for e in line.events
-                          if e.name.startswith(SPAN_PREFIX)]
+    spans = host_spans(pd)
     window = [s for s in spans if s.name == WINDOW_SPAN]
     if not devices or len(window) != 1:
         raise ValueError(f"{path}: {len(devices)} TPU planes and "
